@@ -7,7 +7,7 @@
 
 Prints ``name,us_per_call,derived`` CSV rows; artifacts are written to
 benchmarks/artifacts/. Latencies are analytic TPU-v5e cost-model values
-(see README.md § "Evaluation pipeline" — this host has no TPU);
+(see README.md § "Evaluation pipeline"), not times measured on a chip;
 correctness is interpret-mode Pallas vs the jnp oracles.
 
 Search evaluations go through the tiered engine with a **persistent**
@@ -31,19 +31,6 @@ import argparse
 import glob
 import json
 import os
-import sys
-
-# The serving benchmark's tensor-parallel rows need >= 4 devices; the
-# forced host platform split must land in XLA_FLAGS before anything
-# imports jax (serve_bench is imported lazily, long after jax is live,
-# so it cannot set the flag itself when run through this harness).
-# Single-device rows and kernel timings are unaffected — they run on
-# device 0, whose computation is identical under the virtual split.
-if "jax" not in sys.modules and "--xla_force_host_platform_device_count" \
-        not in os.environ.get("XLA_FLAGS", ""):
-    os.environ["XLA_FLAGS"] = (
-        os.environ.get("XLA_FLAGS", "")
-        + " --xla_force_host_platform_device_count=4").strip()
 
 import numpy as np
 
@@ -365,6 +352,14 @@ def main(argv=None) -> None:
                              "spec_autotune.json; skips kernel searches)")
     args = parser.parse_args(argv)
 
+    # The serving benchmark's tensor-parallel rows need >= 4 devices: split
+    # the host platform before jax starts its backend. Single-device rows
+    # and kernel timings are unaffected — they run on device 0, whose
+    # computation is identical under the virtual split.
+    from repro.launch import compile_cache
+    from repro.launch.mesh import force_host_devices
+    force_host_devices(4)
+    compile_cache.configure()
     os.makedirs(ART, exist_ok=True)
     if args.autotune_spec:
         autotune_spec()

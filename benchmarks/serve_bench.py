@@ -75,16 +75,6 @@ import os
 import sys
 import time
 
-# The sharded serving rows need >= 4 devices; forcing the host platform
-# device count must happen before jax initializes its backend. Device
-# rows are unaffected: single-device engines run on device 0, whose
-# computation is identical with or without the virtual split.
-if "jax" not in sys.modules and "--xla_force_host_platform_device_count" \
-        not in os.environ.get("XLA_FLAGS", ""):
-    os.environ["XLA_FLAGS"] = (
-        os.environ.get("XLA_FLAGS", "")
-        + " --xla_force_host_platform_device_count=4").strip()
-
 import numpy as np
 
 ART = os.path.join(os.path.dirname(__file__), "artifacts")
@@ -389,9 +379,9 @@ def bench_arch(arch: str, mixes=MIXES, *, compare: bool = False,
     # bit-identical to the single-device row (streams_match_sharded).
     sharded_mesh = None
     if cfg.family == "dense" and len(jax.devices()) >= 4:
-        from jax.sharding import Mesh
-        sharded_mesh = Mesh(
-            np.array(jax.devices()[:4]).reshape(2, 2), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        sharded_mesh = make_mesh((2, 2), ("data", "model"),
+                                 devices=jax.devices()[:4])
     rows = []
     for mix in mixes:
         kw = dict(slots=SLOTS, max_seq=MAX_SEQ)
@@ -634,6 +624,23 @@ def main(argv=None) -> int:
     ap.add_argument("--out", default=None, help=argparse.SUPPRESS)
     args = ap.parse_args(argv)
 
+    # The sharded serving rows need >= 4 devices: split the host platform
+    # before jax starts its backend. Device rows are unaffected: single-
+    # device engines run on device 0, whose computation is identical with
+    # or without the virtual split.
+    import jax
+    from repro.launch import compile_cache
+    from repro.launch.mesh import force_host_devices
+    force_host_devices(4)
+    compile_cache.configure()
+    compare = (args.compare or args.check) and not args.skip_reference
+    if compare and jax.default_backend() == "tpu":
+        # the reference rows run in a child interpreter, and a chip
+        # belongs to the one process that already holds it
+        ap.error("--compare/--check start a reference subprocess, which "
+                 "cannot share the TPU this process holds; pass "
+                 "--skip-reference on a TPU")
+
     mixes = tuple(m for m in args.mixes.split(",") if m)
     if args.reference_only:
         rows = []
@@ -642,7 +649,6 @@ def main(argv=None) -> int:
         with open(args.out, "w") as f:
             json.dump(rows, f)
         return 0
-    compare = (args.compare or args.check) and not args.skip_reference
     rows = bench(tuple(args.archs or DEFAULT_ARCHS), mixes,
                  compare=compare, check=args.check and not
                  args.skip_reference)

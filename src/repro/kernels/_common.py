@@ -1,25 +1,9 @@
-"""Shared helpers for the Pallas kernels: padding, tiling, alignment,
-and small compatibility shims across jax/pallas versions."""
+"""Shared helpers for the Pallas kernels: padding, tiling, alignment."""
 
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
-
-# ``pltpu.CompilerParams`` was ``TPUCompilerParams`` before jax 0.5.
-CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or getattr(pltpu, "TPUCompilerParams")
-
-
-def reciprocal(x: jax.Array, *, approx: bool = False) -> jax.Array:
-    """``pl.reciprocal`` where available (jax >= 0.5), else plain divide —
-    the exact semantics of the non-approximate path."""
-    fn = getattr(pl, "reciprocal", None)
-    if fn is not None:
-        return fn(x, approx=approx)
-    return 1.0 / x
 
 # TPU register-tile geometry: the VPU operates on (sublane, lane) = (8, 128)
 # fp32 tiles ((16, 128) for bf16). Block shapes should be multiples of these

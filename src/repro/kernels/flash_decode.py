@@ -49,7 +49,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import _common, ref
+from repro.kernels import ref
 from repro.kernels._common import round_up
 from repro.kernels.registry import (KernelSpace, Knob, TestCase,
                                     register_kernel_space)
@@ -110,7 +110,7 @@ def _online_softmax_step(q, k, v, acc_ref, m_ref, l_ref, *,
 def _finalize_output(o_ref, acc_ref, l_ref, *, use_reciprocal):
     l = l_ref[...][:, :1]
     if use_reciprocal:
-        inv = jnp.where(l > 0, _common.reciprocal(l, approx=False), 0.0)
+        inv = jnp.where(l > 0, pl.reciprocal(l, approx=False), 0.0)
         o_ref[0] = (acc_ref[...] * inv).astype(o_ref.dtype)
     else:
         safe_l = jnp.where(l > 0, l, 1.0)
@@ -119,10 +119,10 @@ def _finalize_output(o_ref, acc_ref, l_ref, *, use_reciprocal):
 
 def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref,
             acc_ref, m_ref, l_ref, *,
-            chunk, sm_scale, use_reciprocal, mask_oob):
+            chunk, hkv, sm_scale, use_reciprocal, mask_oob):
     j = pl.program_id(1)
     n_chunks = pl.num_programs(1)
-    kv_len = len_ref[0, 0]
+    kv_len = len_ref[pl.program_id(0) // hkv]     # scalar-prefetched [b]
 
     @pl.when(j == 0)
     def _init():
@@ -189,33 +189,36 @@ def flash_decode_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     # [b*hkv, s_pad, dh]
     k3 = jnp.swapaxes(k, 1, 2).reshape(b * hkv, s_pad, dh)
     v3 = jnp.swapaxes(v, 1, 2).reshape(b * hkv, s_pad, dh)
-    len2 = jnp.repeat(kv_len.astype(jnp.int32), hkv).reshape(b * hkv, 1)
 
-    grid = (b * hkv, n_chunks)
     kern = functools.partial(
-        _kernel, chunk=chunk, sm_scale=sm_scale,
+        _kernel, chunk=chunk, hkv=hkv, sm_scale=sm_scale,
         use_reciprocal=variant.use_reciprocal, mask_oob=variant.mask_oob)
 
-    out = pl.pallas_call(
-        kern, grid=grid,
+    # kv_len rides in SMEM as a scalar-prefetch operand (as in the paged
+    # kernel): a (1, 1) SMEM block over a VMEM-tiled array breaks Mosaic's
+    # (8, 128) block rule, which interpret mode never checks
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(b * hkv, n_chunks),
         in_specs=[
-            pl.BlockSpec((1, 1), lambda i, j: (i, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, g_pad, dh), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((1, chunk, dh), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, chunk, dh), lambda i, j: (i, j, 0)),
+            pl.BlockSpec((1, g_pad, dh), lambda i, j, ln: (i, 0, 0)),
+            pl.BlockSpec((1, chunk, dh), lambda i, j, ln: (i, j, 0)),
+            pl.BlockSpec((1, chunk, dh), lambda i, j, ln: (i, j, 0)),
         ],
-        out_specs=pl.BlockSpec((1, g_pad, dh), lambda i, j: (i, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((b * hkv, g_pad, dh), q.dtype),
+        out_specs=pl.BlockSpec((1, g_pad, dh), lambda i, j, ln: (i, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((g_pad, dh), jnp.float32),
             pltpu.VMEM((g_pad, 128), jnp.float32),
             pltpu.VMEM((g_pad, 128), jnp.float32),
         ],
-        compiler_params=_common.CompilerParams(
+    )
+    out = pl.pallas_call(
+        kern, grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b * hkv, g_pad, dh), q.dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(len2, q3, k3, v3)
+    )(kv_len.astype(jnp.int32), q3, k3, v3)
 
     out = out.reshape(b, hkv, g_pad, dh)[:, :, :group].reshape(b, hq, dh)
     if not return_lse:
@@ -455,7 +458,7 @@ def paged_flash_decode_attention(q: jax.Array, k_pages: jax.Array,
     out = pl.pallas_call(
         kern, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b * hkv, g_pad, dh), q.dtype),
-        compiler_params=_common.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(flat_pt, kv_len.astype(jnp.int32), q3, k4, v4)

@@ -37,7 +37,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels import _common, ref
+from repro.kernels import ref
 from repro.kernels._common import pad_rows, round_up, sublane_for
 from repro.kernels.registry import (KernelSpace, Knob, TestCase,
                                     register_kernel_space)
@@ -72,7 +72,7 @@ def _weights(sa, sb, *, use_reciprocal):
     wb = jnp.exp(sb - m_safe)
     denom = wa + wb
     if use_reciprocal:
-        inv = jnp.where(denom > 0, _common.reciprocal(denom, approx=False),
+        inv = jnp.where(denom > 0, pl.reciprocal(denom, approx=False),
                         0.0)
     else:
         inv = jnp.where(denom > 0, 1.0 / denom, 0.0)

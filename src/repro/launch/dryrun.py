@@ -1,7 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
-                           + " --xla_force_host_platform_device_count=512").strip()
-
 """Multi-pod dry-run: lower + compile every (arch x shape) on the
 production meshes with 512 placeholder host devices.
 
@@ -17,8 +13,8 @@ Usage:
 """
 
 import argparse
-import dataclasses
 import json
+import os
 import traceback
 
 import jax
@@ -123,7 +119,7 @@ def lower_cell(cfg: ModelConfig, spec: ShapeSpec, mesh, *,
         args = (p_specs, c_specs, tok_spec, pos_spec)
         tokens = spec.global_batch            # one new token per sequence
 
-    with mesh:
+    with jax.set_mesh(mesh):
         lowered = fn.lower(*args)
         compiled = lowered.compile()
     cost = compiled.cost_analysis()
@@ -183,6 +179,12 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, out_dir=None,
 
 
 def main():
+    # 512 placeholder host devices for the production meshes; read when
+    # the backend starts, which is after this (no device is touched at
+    # import)
+    os.environ["XLA_FLAGS"] = (
+        os.environ.get("XLA_FLAGS", "")
+        + " --xla_force_host_platform_device_count=512").strip()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None, choices=list(configs.ARCH_IDS))
     ap.add_argument("--shape", default=None, choices=list(SHAPES))

@@ -3,9 +3,12 @@ SamplingParams (greedy / temperature / top-k / top-p, per-request seed),
 a pluggable scheduler (fcfs / priority / sjf), and the unified cache
 manager (contiguous or paged KV with preemption for PAGED_OK families).
 
-CPU-runnable:
+CPU-runnable (``configs.smoke`` widths unless ``--full``):
     PYTHONPATH=src python -m repro.launch.serve --arch qwen2-0.5b \
         --requests 6 --slots 3 --max-new 8
+    # the published widths (on a TPU; see chip_smoke.py):
+    PYTHONPATH=src python -m repro.launch.serve --full --slots 8 \
+        --max-seq 2048 --prompt-len 512 --max-new 32
     # non-greedy, seeded (reproducible):
     PYTHONPATH=src python -m repro.launch.serve --temperature 0.8 \
         --top-k 40 --top-p 0.95 --seed 7
@@ -17,8 +20,10 @@ CPU-runnable:
     PYTHONPATH=src python -m repro.launch.serve --requests 6 \
         --chaos "abort@2:rid=1,device_fault@5:slot=0" --deadline 30
     # tensor-parallel over a forced-host 4-device mesh (data=2, model=2);
-    # streams are bit-identical to the single-device run:
-    XLA_FLAGS=--xla_force_host_platform_device_count=4 \
+    # streams are bit-identical to the single-device run (single-threaded
+    # Eigen, as launch/mesh.force_host_devices sets it):
+    XLA_FLAGS="--xla_force_host_platform_device_count=4 \
+    --xla_cpu_multi_thread_eigen=false" \
         PYTHONPATH=src python -m repro.launch.serve --tp 2 --slots 4
     # speculative decoding (greedy only; streams bit-identical to the
     # target-only run, in fewer steps):
@@ -33,11 +38,14 @@ import time
 
 import jax
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 from repro import configs
+from repro.launch import compile_cache
 from repro.models import registry
 from repro.serving import (ChaosInjector, LLMEngine, SamplingParams,
                            SpecConfig)
+from repro.sharding import tp as tp_plan
 
 _LIFECYCLE = ("aborted", "rejected", "failed", "deadline_expired",
               "recoveries")
@@ -76,6 +84,43 @@ def _make_spec(spec: str, k: int, cfg, seed: int):
                       draft_params=draft_params, draft_cfg=dcfg)
 
 
+def init_params(cfg, seed: int = 0, *, plan=None, dtype=None):
+    """Random weights from ``seed``, made by one jitted program straight
+    into their placement: the default device, or the shardings of a mesh
+    ``plan`` (``repro.sharding.tp``) — nothing is built whole on one
+    device first. They are unpermuted either way: the engine's
+    ``tp.shard_params`` permutes the gate/up columns. ``dtype`` stores
+    the weight matrices (ndim >= 2) in it, e.g. ``cfg.dtype``: the model
+    casts every matrix to its compute dtype where it uses it, so serving
+    streams do not change, and the weights take half float32's bytes."""
+    def build(key):
+        params, _ = registry.init(cfg, key)
+        if plan is not None:
+            # draw every matrix split over ``model``, whatever its final
+            # placement: a replicated float32 draw of the stacked w_down
+            # alone would take most of a chip
+            params = jax.tree.map(_split_draw, params)
+        if dtype is not None:
+            params = jax.tree.map(
+                lambda x: x.astype(dtype) if x.ndim >= 2 else x, params)
+        return params
+
+    def _split_draw(x):
+        axes = [a for a in range(x.ndim) if x.shape[a] % plan.model == 0]
+        if x.ndim < 2 or not axes:
+            return x
+        spec = [None] * x.ndim
+        spec[max(axes, key=lambda a: x.shape[a])] = "model"
+        return jax.lax.with_sharding_constraint(
+            x, NamedSharding(plan.mesh, PartitionSpec(*spec)))
+
+    key = jax.random.PRNGKey(seed)
+    if plan is None:
+        return jax.jit(build)(key)
+    shardings = tp_plan.param_shardings(jax.eval_shape(build, key), plan)
+    return jax.jit(build, out_shardings=shardings)(key)
+
+
 def run(*, arch: str = "qwen2-0.5b", smoke: bool = True, requests: int = 6,
         slots: int = 3, max_new: int = 8, max_seq: int = 128,
         prompt_len: int = 16, seed: int = 0, verbose: bool = True,
@@ -86,12 +131,14 @@ def run(*, arch: str = "qwen2-0.5b", smoke: bool = True, requests: int = 6,
         chaos: str | None = None, tp: int | None = None,
         spec: str | None = None, spec_k: int = 4):
     cfg = configs.smoke(arch) if smoke else configs.get(arch)
-    params, _ = registry.init(cfg, jax.random.PRNGKey(seed))
     injector = ChaosInjector(parse_chaos(chaos)) if chaos else None
-    mesh = None
+    mesh = plan = None
     if tp is not None:
         from repro.launch.mesh import make_local_mesh
         mesh = make_local_mesh(tp)
+        if cfg.family == "dense":
+            plan = tp_plan.make_plan(cfg, mesh, slots)
+    params = init_params(cfg, seed, plan=plan)
     spec_cfg = _make_spec(spec, spec_k, cfg, seed) if spec else None
     llm = LLMEngine(params, cfg, slots=slots, max_seq=max_seq,
                     scheduler=scheduler, page_size=page_size,
@@ -178,6 +225,9 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2-0.5b",
                     choices=list(configs.ARCH_IDS))
+    ap.add_argument("--full", action="store_true",
+                    help="serve the arch's published widths instead of "
+                         "its configs.smoke reduction")
     ap.add_argument("--requests", type=int, default=6)
     ap.add_argument("--slots", type=int, default=3)
     ap.add_argument("--max-new", type=int, default=8)
@@ -223,7 +273,9 @@ def main():
                     help="draft tokens proposed per decode step "
                          "(the fused verify scores k+1 positions)")
     args = ap.parse_args()
-    run(arch=args.arch, requests=args.requests, slots=args.slots,
+    compile_cache.configure()
+    run(arch=args.arch, smoke=not args.full, requests=args.requests,
+        slots=args.slots,
         max_new=args.max_new, max_seq=args.max_seq,
         prompt_len=args.prompt_len, page_size=args.page_size,
         num_pages=args.num_pages, prefix_cache=not args.no_prefix_cache,

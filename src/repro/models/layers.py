@@ -63,9 +63,11 @@ def split_tree(pairs: dict):
 # ---------------------------------------------------------------------------
 
 def _current_mesh():
-    from jax.interpreters import pxla
-    m = pxla.thread_resources.env.physical_mesh
-    return None if m.empty else m
+    """The mesh set by ``jax.set_mesh``, or None — also inside the serving
+    engine's ``shard_map`` bodies, whose axes are manual and whose
+    placement ``sharding.tp`` owns."""
+    m = jax.sharding.get_abstract_mesh()
+    return None if m.empty or m.manual_axes else m
 
 
 def shard_batch(x, n_batch_dims: int = 1):
@@ -514,7 +516,7 @@ def mlp_block(p, x):
 
     Under an active serving TP plan ``w_gateup`` columns are sharded
     (pre-permuted so each shard holds its own gate/up pair, see
-    ``sharding.tp.gateup_permutation``); the local ``silu_and_mul``
+    ``sharding.tp.permute_gateup``); the local ``silu_and_mul``
     outputs are all-gathered before the replicated down projection."""
     h = jnp.einsum("bsd,df->bsf", x, p["w_gateup"].astype(x.dtype))
     h = ops.silu_and_mul(h)

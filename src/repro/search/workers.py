@@ -158,19 +158,24 @@ class _Worker:
         self.tasks_done = 0
         # the spawned interpreter must be able to import repro; tests often
         # run with sys.path tweaks that children don't inherit, so splice
-        # the package root into PYTHONPATH around start()
-        old = os.environ.get("PYTHONPATH")
-        parts = [env_path] + ([old] if old else [])
+        # the package root into PYTHONPATH around start(). Workers only
+        # interpret kernels, so they are pinned to the CPU: a chip belongs
+        # to one process, and the parent may hold it.
+        old = {k: os.environ.get(k) for k in ("PYTHONPATH", "JAX_PLATFORMS")}
+        parts = [env_path] + ([old["PYTHONPATH"]] if old["PYTHONPATH"]
+                              else [])
         os.environ["PYTHONPATH"] = os.pathsep.join(parts)
+        os.environ["JAX_PLATFORMS"] = "cpu"
         try:
             self.proc = ctx.Process(target=_worker_main, args=(child,),
                                     daemon=True)
             self.proc.start()
         finally:
-            if old is None:
-                os.environ.pop("PYTHONPATH", None)
-            else:
-                os.environ["PYTHONPATH"] = old
+            for k, v in old.items():
+                if v is None:
+                    os.environ.pop(k, None)
+                else:
+                    os.environ[k] = v
         child.close()
 
     def alive(self) -> bool:

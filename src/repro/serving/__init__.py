@@ -30,7 +30,7 @@ from repro.serving.cache_manager import (CacheConfig, CacheManager,
                                          ContiguousCacheManager,
                                          PagedCacheManager)
 from repro.serving.chaos import ChaosInjector, InjectedDeviceFault
-from repro.serving.engine import Engine, Request
+from repro.serving.engine import Engine, ProgramError, Request
 from repro.serving.reference import ReferenceEngine
 from repro.serving.sampling import SamplingParams
 from repro.serving.scheduler import (FCFSScheduler, PreemptionPolicy,
@@ -46,6 +46,7 @@ __all__ = [
     "ContiguousCacheManager", "DraftModelDrafter", "Drafter", "Engine",
     "FCFSScheduler", "InjectedDeviceFault", "LLMEngine",
     "NGramDrafter", "PagedCacheManager", "PreemptionPolicy",
+    "ProgramError",
     "PriorityScheduler", "RecomputePreemption", "ReferenceEngine",
     "Request", "RequestOutput", "SJFScheduler", "SamplingParams",
     "Scheduler", "SpecConfig", "SwapPreemption", "TokenEvent",

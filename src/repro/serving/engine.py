@@ -76,19 +76,15 @@ def _quiet_donation():
         yield
 
 
-def _jit_cache_size(fn) -> Optional[int]:
-    """Compiled-program count of a jitted callable via jax's private
-    ``_cache_size`` API; None when the API is absent (jax version drift).
-    Typed narrowly on purpose: the engine's failure-isolation layer
-    swallows per-request faults, never introspection errors — anything
-    other than the known drift modes here must surface."""
-    size = getattr(fn, "_cache_size", None)
-    if size is None:
-        return None
-    try:
-        return int(size())
-    except TypeError:    # drift: no longer a nullary callable / not an int
-        return None
+class ProgramError(Exception):
+    """A jitted program raised before it had ever completed a dispatch.
+
+    That is a failure of the program itself on this backend — it does not
+    compile or launch (a Mosaic or XLA error) — and no request caused it.
+    Deliberately not a ``RuntimeError``: the per-request failure isolation
+    below catches those, and would turn a program that cannot run into
+    ``failed`` outputs and a clean exit. The original error is the
+    ``__cause__``."""
 
 
 @dataclasses.dataclass
@@ -201,6 +197,9 @@ class Engine:
                                             num_pages=num_pages)
         self.params, self.cfg = params, cfg
         self.n_slots, self.max_seq = slots, max_seq
+        # (callable, argument signature) of every compiled program that
+        # has completed a dispatch (``_dispatch``)
+        self._proven: set = set()
         self.slots = [_Slot() for _ in range(slots)]
         self.default_sampling = sampling if sampling is not None \
             else SamplingParams()
@@ -270,7 +269,8 @@ class Engine:
         if spec is not None and self.paged and cfg.frontend != "frames":
             self.spec = spec
             self._drafter = make_drafter(spec, cfg, slots, max_seq,
-                                         dev=self._dev)
+                                         dev=self._dev,
+                                         dispatch=self._dispatch)
             # the fused draft-verify step: k+1 sequential inner decode
             # steps in ONE donated program (greedy-only by construction
             # — non-greedy requests are rejected at admission)
@@ -293,6 +293,30 @@ class Engine:
         self._readbacks = 0
         self._prefill_shapes: set[tuple] = set()
         self._suffix_shapes: set[int] = set()
+
+    def _dispatch(self, fn, *args):
+        """Run one jitted program. A ``RuntimeError`` from a compiled
+        program that has already completed a dispatch is a per-request
+        fault, left to the caller's isolation; from one that never has,
+        it is raised as ``ProgramError``. One callable compiles a program
+        per argument signature (each prefill bucket is its own), so a
+        program is keyed by the callable and its arguments' shapes, dtypes
+        and shardings."""
+        key = (fn, tuple(
+            (np.shape(a), getattr(a, "dtype", type(a)),
+             getattr(a, "sharding", None))
+            for a in jax.tree.leaves(args)))
+        try:
+            with _quiet_donation():
+                out = fn(*args)
+        except RuntimeError as e:
+            if key in self._proven:
+                raise
+            raise ProgramError(
+                f"{getattr(fn, '__name__', 'program')} failed on its first "
+                f"dispatch: {e}") from e
+        self._proven.add(key)
+        return out
 
     # -- device placement (mesh) ---------------------------------------------
 
@@ -802,14 +826,10 @@ class Engine:
             # in-flight state is unaffected)
             self._greedy_only = False
             self._step_fn = self._jit_step(False)
-            n = _jit_cache_size(self._admit_fn)
-            if n is not None:
-                self._compiles_base += n
+            self._compiles_base += self._admit_fn._cache_size()
             self._admit_fn = self._jit_admit(False)
             if self._prefix_cache:
-                n = _jit_cache_size(self._admit_suffix_fn)
-                if n is not None:
-                    self._compiles_base += n
+                self._compiles_base += self._admit_suffix_fn._cache_size()
                 self._admit_suffix_fn = self._jit_admit_suffix(False)
         return sp
 
@@ -868,17 +888,16 @@ class Engine:
 
     def _dispatch_restore(self, i: int, req: Request, sp, pages):
         saved, tok, dpos, demitted = req.swap_state[:4]
-        with _quiet_donation():
-            return self._restore_fn(
-                self.cache, self._token, self._pos, self._active,
-                self._emitted, self._max_new, self._keys, self._temp,
-                self._topk, self._topp,
-                jax.tree.map(jnp.asarray, saved), jnp.int32(tok),
-                jnp.int32(dpos), jnp.int32(demitted),
-                jnp.int32(req.max_new_tokens),
-                jnp.int32(sp.resolve_seed(req.rid)),
-                jnp.float32(sp.temperature), jnp.int32(sp.top_k),
-                jnp.float32(sp.top_p), jnp.int32(i), pages)
+        return self._dispatch(
+            self._restore_fn, self.cache, self._token, self._pos,
+            self._active, self._emitted, self._max_new, self._keys,
+            self._temp, self._topk, self._topp,
+            jax.tree.map(jnp.asarray, saved), jnp.int32(tok),
+            jnp.int32(dpos), jnp.int32(demitted),
+            jnp.int32(req.max_new_tokens),
+            jnp.int32(sp.resolve_seed(req.rid)),
+            jnp.float32(sp.temperature), jnp.int32(sp.top_k),
+            jnp.float32(sp.top_p), jnp.int32(i), pages)
 
     def _admit(self):
         for i, slot in enumerate(self.slots):
@@ -936,8 +955,7 @@ class Engine:
                                 jnp.int32(sp.top_k), jnp.float32(sp.top_p))
                         if self.paged:
                             args += (pages_arg,)
-                        with _quiet_donation():
-                            out = self._admit_fn(*args)
+                        out = self._dispatch(self._admit_fn, *args)
                         (self.cache, self._token, self._pos, self._active,
                          self._emitted, self._max_new, self._keys,
                          self._temp, self._topk, self._topp, tok0) = out
@@ -1001,9 +1019,8 @@ class Engine:
             # private copy; duplicate the shared page's bytes first so the
             # copy also holds rows the suffix program won't rewrite
             src, dst = plan["cow"]
-            with _quiet_donation():
-                self.cache = self._cow_fn(self.cache, jnp.int32(src),
-                                          jnp.int32(dst))
+            self.cache = self._dispatch(self._cow_fn, self.cache,
+                                        jnp.int32(src), jnp.int32(dst))
         self._suffix_shapes.add(sb)
         args = (self.params, self.cache, self._token, self._pos,
                 self._active, self._emitted, self._max_new,
@@ -1016,8 +1033,7 @@ class Engine:
                 jnp.int32(sp.resolve_seed(req.rid)),
                 jnp.float32(sp.temperature), jnp.int32(sp.top_k),
                 jnp.float32(sp.top_p))
-        with _quiet_donation():
-            out = self._admit_suffix_fn(*args)
+        out = self._dispatch(self._admit_suffix_fn, *args)
         (self.cache, self._token, self._pos, self._active,
          self._emitted, self._max_new, self._keys, self._temp,
          self._topk, self._topp, tok0) = out
@@ -1242,10 +1258,7 @@ class Engine:
                     if self.chaos is not None and self.chaos.relent(self):
                         return True
                 return False
-        args = (self.params, self.cache, self._token, self._pos,
-                self._active, self._emitted, self._max_new, self._keys,
-                self._temp, self._topk, self._topp)
-        args += tuple(jnp.asarray(x) for x in self.cm.step_extra())
+        args = self._step_args()
         try:
             if self.chaos is not None:
                 # BEFORE the draft propose: an injected fault then leaves
@@ -1255,11 +1268,10 @@ class Engine:
             if self.spec is not None:
                 drafts = self._drafter.propose(self.slots, self._token,
                                                self._pos)
-                with _quiet_donation():
-                    out = self._spec_step_fn(*args, jnp.asarray(drafts))
+                out = self._dispatch(self._spec_step_fn, *args,
+                                     jnp.asarray(drafts))
             else:
-                with _quiet_donation():
-                    out = self._step_fn(*args)
+                out = self._dispatch(self._step_fn, *args)
         except RuntimeError as e:     # XlaRuntimeError subclasses this
             self._recover_step_fault(e)
             return True
@@ -1293,6 +1305,24 @@ class Engine:
         if prev is not None:
             self._apply(prev)           # readback of step k-1 overlaps k
         return True
+
+    def _step_args(self) -> tuple:
+        """The plain decode step's arguments as the next dispatch would
+        pass them (carries, then the cache manager's page table)."""
+        return (self.params, self.cache, self._token, self._pos,
+                self._active, self._emitted, self._max_new, self._keys,
+                self._temp, self._topk, self._topp) \
+            + tuple(jnp.asarray(x) for x in self.cm.step_extra())
+
+    def step_program_text(self) -> str:
+        """StableHLO of the decode step program the engine dispatches now
+        (lowered, not compiled or run) — e.g. to check that its kernels
+        are Pallas custom calls and not the jnp references."""
+        fn = self._spec_step_fn if self.spec is not None else self._step_fn
+        args = self._step_args()
+        if self.spec is not None:
+            args += (jnp.zeros((self.n_slots, self.spec.k), jnp.int32),)
+        return fn.lower(*args).as_text()
 
     def _sample_page_stats(self):
         rows = {i: min(s.dpos, self.max_seq)
@@ -1435,15 +1465,10 @@ class Engine:
         """Decode steps, prefill retrace count, bucket coverage, scheduler
         counters, and (paged) preemption + page-pool utilization/
         fragmentation."""
-        n = _jit_cache_size(self._admit_fn)
-        if n is None:       # private jax API gone: shape-count fallback
-            prefill_compiles = len(self._prefill_shapes) \
-                + len(self._suffix_shapes)
-        else:
-            prefill_compiles = self._compiles_base + n
-            if self._prefix_cache:
-                prefill_compiles += \
-                    _jit_cache_size(self._admit_suffix_fn) or 0
+        prefill_compiles = self._compiles_base \
+            + self._admit_fn._cache_size()
+        if self._prefix_cache:
+            prefill_compiles += self._admit_suffix_fn._cache_size()
         out = {
             "steps": self._steps,
             "readbacks": self._readbacks,

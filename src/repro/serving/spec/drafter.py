@@ -132,11 +132,16 @@ class DraftModelDrafter(Drafter):
     stateful = True
 
     def __init__(self, params, cfg, k: int, slots: int, max_seq: int,
-                 dev=None):
-        """``dev`` places arrays for the engine's mesh (identity off)."""
+                 dev=None, dispatch=None):
+        """``dev`` places arrays for the engine's mesh (identity off);
+        ``dispatch(fn, *args)`` runs each jitted program (the engine's,
+        so a draft program that cannot run is not taken for a request's
+        fault; a plain call by default)."""
         self.cfg, self.k = cfg, k
         self.slots, self.max_seq = slots, max_seq
         self._dev = dev if dev is not None else (lambda x: x)
+        self._run = dispatch if dispatch is not None \
+            else (lambda fn, *args: fn(*args))
         self.params = jax.tree.map(self._dev, params)
         _, self._axes = registry.cache_spec(cfg, slots, max_seq)
         self.cache = self._fresh_cache()
@@ -198,8 +203,8 @@ class DraftModelDrafter(Drafter):
 
     def propose(self, slots, token, pos):
         """One donated scan dispatch; drafts stay on device."""
-        self.cache, drafts = self._scan_fn(self.params, self.cache,
-                                           token, pos)
+        self.cache, drafts = self._run(self._scan_fn, self.params,
+                                       self.cache, token, pos)
         return drafts
 
     def prefill(self, slot, tokens) -> None:
@@ -214,9 +219,9 @@ class DraftModelDrafter(Drafter):
         if b > n:
             tokens = np.concatenate(
                 [tokens, np.zeros((b - n,), tokens.dtype)])
-        self.cache = self._prefill_fn(self.params, self.cache,
-                                      jnp.asarray(tokens), jnp.int32(n),
-                                      jnp.int32(slot))
+        self.cache = self._run(self._prefill_fn, self.params, self.cache,
+                               jnp.asarray(tokens), jnp.int32(n),
+                               jnp.int32(slot))
 
     def snapshot_slot(self, slot):
         """Host copy of ``slot``'s rows on every cache leaf (swap-out)."""
@@ -233,9 +238,9 @@ class DraftModelDrafter(Drafter):
 
     def restore_slot(self, slot, saved) -> None:
         """Byte-for-byte swap-in of a ``snapshot_slot`` payload."""
-        self.cache = self._restore_fn(
-            self.cache, jax.tree.map(jnp.asarray, saved),
-            jnp.int32(slot))
+        self.cache = self._run(self._restore_fn, self.cache,
+                               jax.tree.map(jnp.asarray, saved),
+                               jnp.int32(slot))
 
     def reset(self) -> None:
         """Fresh zeroed cache (same shapes/placement: no retrace)."""
@@ -243,7 +248,7 @@ class DraftModelDrafter(Drafter):
 
 
 def make_drafter(spec: SpecConfig, cfg, slots: int, max_seq: int,
-                 dev=None) -> Drafter:
+                 dev=None, dispatch=None) -> Drafter:
     """Resolve a ``SpecConfig`` into a ready drafter for this engine.
 
     Validates the draft model against the target: token frontend, exact
@@ -266,4 +271,4 @@ def make_drafter(spec: SpecConfig, cfg, slots: int, max_seq: int,
             f"draft vocab {dcfg.vocab} cannot cover target vocab "
             f"{cfg.vocab}: proposals are target token ids")
     return DraftModelDrafter(spec.draft_params, dcfg, spec.k, slots,
-                             max_seq, dev=dev)
+                             max_seq, dev=dev, dispatch=dispatch)

@@ -13,8 +13,11 @@ bodies need:
   documented fallback, exercised by qwen2's 2 smoke / 14 full heads.
 * ``shard_params`` / ``param_specs`` / ``kv_spec`` — physical placement
   of the dense-family weight tree and the paged KV pool. The fused
-  gate/up projection is column-pre-permuted so each model shard holds
-  its own ``(gate_m, up_m)`` pair and ``silu_and_mul`` splits locally.
+  gate/up projection is column-pre-permuted (``permute_gateup``) so each
+  model shard holds its own ``(gate_m, up_m)`` pair and
+  ``silu_and_mul`` splits locally. ``param_shardings`` lets an
+  initializer build the (unpermuted) weights straight into this layout
+  (``repro.launch.serve.init_params``) when one device cannot hold them.
 * ``active`` / ``current`` — a trace-time context: the engine enters
   the plan inside the ``shard_map`` body, so the *unchanged* model code
   in :mod:`repro.models` sees it while tracing and routes through the
@@ -39,11 +42,10 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 
 import jax
-import numpy as np
 from jax import lax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.sharding.rules import _resolve
@@ -154,46 +156,56 @@ def kv_specs(plan: Plan) -> dict:
     return {"k": s, "v": s}
 
 
-def _put(tree, specs, mesh: Mesh):
-    """device_put ``tree`` with a matching PartitionSpec tree (specs are
-    tuples, hence the flatten_up_to dance — same as rules.tree_shardings)."""
+def _shardings(tree, specs, mesh: Mesh):
+    """``NamedSharding`` tree matching ``tree`` (which may hold
+    ``ShapeDtypeStruct``s) from a PartitionSpec tree — specs are tuples,
+    hence the flatten_up_to dance, as in ``rules.tree_shardings``."""
     flat, treedef = jax.tree.flatten(tree)
-    flat_s = treedef.flatten_up_to(specs)
-    out = [jax.device_put(x, NamedSharding(mesh, s))
-           for x, s in zip(flat, flat_s)]
-    return jax.tree.unflatten(treedef, out)
+    return jax.tree.unflatten(treedef, [
+        NamedSharding(mesh, s) for s in treedef.flatten_up_to(specs)])
 
 
-def gateup_permutation(d_ff: int, model: int) -> np.ndarray:
-    """Column permutation putting ``(gate_m, up_m)`` on model shard m.
+def permute_gateup(wg, cfg, plan: Plan):
+    """Put ``(gate_m, up_m)`` on model shard m.
 
-    ``w_gateup [D, 2F]`` fuses gate columns ``[0, F)`` and up columns
+    ``w_gateup [.., D, 2F]`` fuses gate columns ``[0, F)`` and up columns
     ``[F, 2F)``; naive column sharding would hand shard 0 gate-only
-    columns. The permuted order is pure column movement, so gathering
+    columns. The new order, ``[.., (gate|up), model, F/model] -> [..,
+    model, (gate|up), F/model]``, is pure column movement, so gathering
     the per-shard ``silu_and_mul`` outputs restores the original column
-    order bitwise (``gather_mlp``)."""
-    fl = d_ff // model
-    return np.concatenate([
-        np.r_[m * fl:(m + 1) * fl, d_ff + m * fl:d_ff + (m + 1) * fl]
-        for m in range(model)])
+    order bitwise (``gather_mlp``). As a reshape/transpose it partitions
+    as an all-to-all, not a gather of the whole matrix onto one device."""
+    lead = wg.shape[:-1]
+    wg = wg.reshape(*lead, 2, plan.model, cfg.d_ff // plan.model)
+    return jax.numpy.swapaxes(wg, -3, -2).reshape(*lead, 2 * cfg.d_ff)
+
+
+def param_shardings(params: dict, plan: Plan) -> dict:
+    """``NamedSharding`` tree of ``param_specs``."""
+    return _shardings(params, param_specs(params, plan), plan.mesh)
 
 
 def shard_params(params: dict, cfg, plan: Plan) -> dict:
-    """Place the weight tree on the mesh per ``param_specs`` (permuting
-    the fused gate/up columns when the MLP axis shards)."""
-    if plan.mlp:
-        perm = gateup_permutation(cfg.d_ff, plan.model)
-        wg = jax.numpy.take(params["layers"]["mlp"]["w_gateup"],
-                            jax.numpy.asarray(perm), axis=-1)
-        layers = dict(params["layers"])
-        layers["mlp"] = dict(layers["mlp"], w_gateup=wg)
-        params = dict(params, layers=layers)
-    return _put(params, param_specs(params, plan), plan.mesh)
+    """Place an unpermuted weight tree on the mesh per ``param_specs``,
+    then permute the fused gate/up columns there when the MLP axis
+    shards: one jitted program over the placed matrix, so no device holds
+    the whole of it. The caller's tree is left as it was."""
+    shardings = param_shardings(params, plan)
+    placed = jax.device_put(params, shardings)
+    if not plan.mlp:
+        return placed
+    mlp = placed["layers"]["mlp"]
+    wg = jax.jit(functools.partial(permute_gateup, cfg=cfg, plan=plan),
+                 out_shardings=shardings["layers"]["mlp"]["w_gateup"])(
+                     mlp["w_gateup"])
+    layers = dict(placed["layers"], mlp=dict(mlp, w_gateup=wg))
+    return dict(placed, layers=layers)
 
 
 def put_cache(cache, plan: Plan):
     """Place a (freshly built) KV cache pytree on the mesh."""
-    return _put(cache, kv_specs(plan), plan.mesh)
+    return jax.device_put(cache, _shardings(cache, kv_specs(plan),
+                                            plan.mesh))
 
 
 def replicate(x, plan: Plan):
@@ -285,14 +297,14 @@ def gather_data(x, axis: int = 0):
 def wrap(plan: Plan, fn, in_specs, out_specs, donate_argnums=()):
     """``jit(shard_map(fn))`` with the plan entered inside the body.
 
-    ``check_rep=False`` everywhere: replicated ``P()`` outputs are
+    ``check_vma=False`` everywhere: replicated ``P()`` outputs are
     genuinely identical on every device (they are all-gather results or
     elementwise functions of replicated inputs), but shard_map's
-    replication checker cannot see through the gather pattern."""
+    varying-axes checker cannot see through the gather pattern."""
     def body(*args):
         with active(plan):
             return fn(*args)
 
-    sm = shard_map(body, mesh=plan.mesh, in_specs=in_specs,
-                   out_specs=out_specs, check_rep=False)
+    sm = jax.shard_map(body, mesh=plan.mesh, in_specs=in_specs,
+                       out_specs=out_specs, check_vma=False)
     return jax.jit(sm, donate_argnums=donate_argnums)

@@ -14,7 +14,7 @@ from repro.models import registry
 from repro.reliability import Fault, FaultSchedule
 from repro.serving.cache_manager import CacheConfig
 from repro.serving.chaos import ChaosInjector, InjectedDeviceFault
-from repro.serving.engine import Engine, Request
+from repro.serving.engine import Engine, ProgramError, Request
 from repro.serving.api import LLMEngine
 from repro.training.fault_tolerance import FailureInjector
 
@@ -383,6 +383,59 @@ def test_facade_abort_mid_stream():
     other = next(rid for rid in terminal if rid != first.rid)
     assert terminal[other].finish_reason == "done"
     assert llm.engine._pool.check() is None
+
+
+# -- a program that cannot run is not a request's fault ----------------------
+
+def _failing_step(llm, fail_on_call: int):
+    """Swap the engine's step program for one that raises a plain
+    RuntimeError (what XLA or Mosaic raise) on call ``fail_on_call``."""
+    real, calls = llm.engine._step_fn, []
+
+    def step(*args):
+        calls.append(1)
+        if len(calls) == fail_on_call:
+            raise RuntimeError("program cannot run on this device")
+        return real(*args)
+
+    llm.engine._step_fn = step
+
+
+def test_step_program_failing_on_first_dispatch_raises_from_generate():
+    cfg, params = _setup()
+    llm = LLMEngine(params, cfg, slots=2, max_seq=64)
+    _failing_step(llm, fail_on_call=1)
+    with pytest.raises(ProgramError) as info:
+        llm.generate(_prompts(cfg, n=2), max_new_tokens=4)
+    assert isinstance(info.value.__cause__, RuntimeError)
+    assert not isinstance(info.value, RuntimeError)
+
+
+def test_admit_bucket_failing_on_first_dispatch_raises_from_generate():
+    # each prefill bucket is its own compiled program: the 16-token bucket
+    # succeeding does not vouch for the 64-token one
+    cfg, params = _setup()
+    llm = LLMEngine(params, cfg, slots=2, max_seq=64)
+    real = llm.engine._admit_fn
+
+    def admit(*args):
+        if args[11].shape[0] == 64:      # the padded prompt
+            raise RuntimeError("bucket 64 cannot run on this device")
+        return real(*args)
+
+    llm.engine._admit_fn = admit
+    prompts = _prompts(cfg, n=1, length=12) + _prompts(cfg, n=1, length=40)
+    with pytest.raises(ProgramError, match="bucket 64"):
+        llm.generate(prompts, max_new_tokens=4)
+
+
+def test_step_fault_after_first_success_stays_isolated():
+    cfg, params = _setup()
+    llm = LLMEngine(params, cfg, slots=2, max_seq=64)
+    _failing_step(llm, fail_on_call=3)
+    outs = llm.generate(_prompts(cfg, n=2), max_new_tokens=6)
+    assert sorted(o.finish_reason for o in outs) == ["done", "failed"]
+    assert llm.stats()["recoveries"] == 1
 
 
 # -- chaos page seizure: allocator-level cross-validation --------------------

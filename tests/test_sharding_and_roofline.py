@@ -6,6 +6,7 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
+from repro.launch.mesh import make_mesh
 from repro.roofline import analysis
 from repro.roofline.hlo_parser import Module, _shape_elems_bytes
 from repro.sharding import rules
@@ -16,11 +17,11 @@ def mesh16():
     # 1 real device: build an abstract 4x4 mesh over fake ids is not
     # possible; use a 1x1 mesh for API checks and a fake-device mesh for
     # rule checks via mesh shape introspection only.
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 def test_spec_divisibility_fallback():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     # with axis size 1 everything divides; exercise the structure
     s = rules.spec_for(("embed", "heads", "head_dim"), (896, 14, 64), mesh)
     assert isinstance(s, P)
@@ -61,11 +62,12 @@ os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
 import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro import configs
+from repro.launch.mesh import make_mesh
 from repro.sharding import batch_spec, rules, sharding_for, spec_for, tp
 
-m22 = jax.make_mesh((2, 2), ("data", "model"))
-m14 = jax.make_mesh((1, 4), ("data", "model"))
-pod = jax.make_mesh((2, 2, 1), ("pod", "data", "model"))
+m22 = make_mesh((2, 2), ("data", "model"))
+m14 = make_mesh((1, 4), ("data", "model"))
+pod = make_mesh((2, 2, 1), ("pod", "data", "model"))
 
 # logical -> physical resolution on a real mesh
 qwen2 = configs.get("qwen2-0.5b")     # 14 heads, 2 kv heads, d_ff 4864

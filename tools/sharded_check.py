@@ -6,7 +6,11 @@ and asserts the token streams are **bitwise identical** and the
 deterministic counters (steps, preemptions, prefix hits, CoW copies,
 recoveries) agree. This is the executable proof behind the sharded
 serving design in ``docs/ARCHITECTURE.md``: every cross-device exchange
-is an all-gather, so sharding must not change a single token.
+is an all-gather, so sharding must not change a single token. The
+single-device engine gets the plain ``registry.init`` tree; the sharded
+one gets the same weights built straight into their mesh shardings by
+``repro.launch.serve.init_params``: weights placed from one device and
+weights built on the mesh must give the same streams.
 
 Scenarios:
     greedy     argmax decoding, continuous batching
@@ -15,37 +19,17 @@ Scenarios:
     prefix     radix prefix-cache hits across two request waves
     chaos      injected device fault + swap-restore recovery
 
-Usage (the XLA flag is self-applied when the module is imported first):
+Usage:
     python tools/sharded_check.py --arch qwen3-8b --mesh 2,2 --json
     python tools/sharded_check.py --arch qwen2-0.5b --mesh 1,4
 """
 
 from __future__ import annotations
 
-import os
+import argparse
+import json
 import sys
-
-
-def _want_devices(default: int = 4) -> int:
-    for i, a in enumerate(sys.argv):
-        if a == "--devices" and i + 1 < len(sys.argv):
-            return int(sys.argv[i + 1])
-        if a.startswith("--devices="):
-            return int(a.split("=", 1)[1])
-    return default
-
-
-# Must run before jax is imported anywhere in this process.
-if "jax" not in sys.modules and "--xla_force_host_platform_device_count" \
-        not in os.environ.get("XLA_FLAGS", ""):
-    os.environ["XLA_FLAGS"] = (
-        os.environ.get("XLA_FLAGS", "")
-        + f" --xla_force_host_platform_device_count={_want_devices()}"
-    ).strip()
-
-import argparse  # noqa: E402
-import json  # noqa: E402
-from pathlib import Path  # noqa: E402
+from pathlib import Path
 
 _SRC = Path(__file__).resolve().parents[1] / "src"
 if str(_SRC) not in sys.path:
@@ -55,10 +39,13 @@ import jax  # noqa: E402
 import numpy as np  # noqa: E402
 
 from repro import configs  # noqa: E402
+from repro.launch.mesh import force_host_devices, make_mesh  # noqa: E402
+from repro.launch.serve import init_params  # noqa: E402
 from repro.models import registry  # noqa: E402
 from repro.reliability import Fault  # noqa: E402
 from repro.serving import (ChaosInjector, LLMEngine,  # noqa: E402
                            SamplingParams)
+from repro.sharding import tp  # noqa: E402
 
 SCENARIOS = ("greedy", "sampling", "preempt", "prefix", "chaos")
 
@@ -66,6 +53,9 @@ SCENARIOS = ("greedy", "sampling", "preempt", "prefix", "chaos")
 COMPARE = ("steps", "readbacks", "prefill_compiles", "preemptions",
            "sched_reorders", "prefix_hit_tokens", "cow_copies",
            "recoveries", "aborted", "failed")
+
+
+SLOTS = 4
 
 
 def _prompts(cfg, rng, n, lo=4, hi=16):
@@ -80,7 +70,7 @@ def _streams(outs):
 
 def run_scenario(name: str, cfg, params, mesh):
     """One engine, one scenario; returns (streams, stats)."""
-    kw = dict(slots=4, max_seq=128)
+    kw = dict(slots=SLOTS, max_seq=128)
     chaos = None
     if name == "chaos":
         chaos = ChaosInjector([Fault(kind="device_fault", step=7, slot=1)])
@@ -114,12 +104,13 @@ def check(arch: str, mesh_shape, scenarios=SCENARIOS) -> dict:
     """Run every scenario twice (single-device, sharded) and compare."""
     cfg = configs.smoke(arch)
     params, _ = registry.init(cfg, jax.random.PRNGKey(0))
-    mesh = jax.make_mesh(tuple(mesh_shape), ("data", "model"))
+    mesh = make_mesh(mesh_shape, ("data", "model"))
+    sh_params = init_params(cfg, 0, plan=tp.make_plan(cfg, mesh, SLOTS))
     report = {"arch": arch, "mesh": list(mesh_shape), "scenarios": {},
               "ok": True}
     for name in scenarios:
         base_streams, base_stats = run_scenario(name, cfg, params, None)
-        sh_streams, sh_stats = run_scenario(name, cfg, params, mesh)
+        sh_streams, sh_stats = run_scenario(name, cfg, sh_params, mesh)
         report.setdefault("plan", sh_stats.get("mesh"))
         notes = []
         if base_streams != sh_streams:
@@ -157,11 +148,12 @@ def main():
     ap.add_argument("--mesh", default="2,2",
                     help="data,model axis sizes (e.g. 2,2 or 1,4)")
     ap.add_argument("--devices", type=int, default=4,
-                    help="forced host device count (set before jax init)")
+                    help="forced host device count")
     ap.add_argument("--scenarios", default=None,
                     help=f"comma list from {','.join(SCENARIOS)}")
     ap.add_argument("--json", action="store_true", dest="as_json")
     args = ap.parse_args()
+    force_host_devices(args.devices)
     mesh_shape = tuple(int(x) for x in args.mesh.split(","))
     scenarios = tuple(args.scenarios.split(",")) if args.scenarios \
         else SCENARIOS
