@@ -34,6 +34,13 @@ step with step *k*'s readback overlapped against step *k+1*'s dispatch,
 and bucketed jitted prefill admission (pow2 prompt buckets for
 ``PAD_PREFILL`` families, exact length for stateful ones).
 
+Under a ``jax.profiler`` trace the host side shows up as spans on the
+device's clock: ``engine.step`` around each step, and inside it
+``engine.admit`` (per admission attempt, ``rid``) with its
+``engine.first_token`` read, ``engine.ensure_pages``, ``engine.dispatch``
+and ``engine.readback``. With no profiler running each costs a
+microsecond or two.
+
 Greedy FCFS token streams are bit-identical to the historical host-driven
 engine (``repro.serving.reference.ReferenceEngine``) — paged or not,
 preempted or not; asserted end-to-end in ``tests/test_serving.py`` and by
@@ -99,6 +106,7 @@ class Request:
     out_tokens: list = dataclasses.field(default_factory=list)
     done: bool = False
     t_submit: float = 0.0               # set by Engine.submit
+    t_admit: float = 0.0                # first pop from the queue (admission)
     t_first: float = 0.0                # wall time of the first token (TTFT)
     preemptions: int = 0                # paged engine: times evicted+requeued
     arrival: int = -1                   # submission rank, stamped by submit
@@ -291,7 +299,6 @@ class Engine:
         self._pending = None
         self._steps = 0
         self._readbacks = 0
-        self._prefill_shapes: set[tuple] = set()
         self._suffix_shapes: set[int] = set()
 
     def _dispatch(self, fn, *args):
@@ -903,105 +910,114 @@ class Engine:
         for i, slot in enumerate(self.slots):
             if slot.req is None and len(self.scheduler):
                 req = self.scheduler.peek()
-                if self.paged and req.swap_state is not None:
-                    if not self._readmit_swapped(i, slot, req):
+                with jax.profiler.TraceAnnotation("engine.admit",
+                                                  rid=req.rid):
+                    if not self._admit_head(i, slot, req):
                         return     # head-of-line: admission waits for pages
-                    continue
-                prompt = np.asarray(req.prompt)
-                if req.out_tokens:
-                    # recompute re-admission after preemption: the generated
-                    # prefix joins the prompt, so prefill rebuilds the exact
-                    # logical cache the victim lost
-                    prompt = np.concatenate(
-                        [prompt, np.asarray(req.out_tokens, prompt.dtype)])
-                n = len(prompt)
-                b = self._bucket_len(n)
-                if self._prefix_cache:
-                    # radix-aware hold: maps the longest cached prefix
-                    # read-only + reserves private pages for the rest
-                    plan = self.cm.admit_prompt(i, prompt)
-                    if plan is None:
-                        return     # head-of-line: admission waits for pages
-                else:
-                    plan = None
-                    if not self.cm.alloc(i, n):
-                        return     # head-of-line: admission waits for pages
-                self.scheduler.pop()
-                sp = self._sampling_of(req)
-                try:
-                    if plan is not None and plan["suffix_start"] > 0:
-                        tok0 = self._dispatch_suffix(i, req, prompt, n,
-                                                     plan, sp)
-                        req.prefix_hit_tokens += plan["suffix_start"]
-                    else:
-                        pages_arg = None
-                        if self.paged:
-                            pages_arg = jnp.asarray(
-                                self.cm.prefill_pages(i, n, b))
-                        if b is not None and b > n:
-                            pad = np.zeros((b - n,) + prompt.shape[1:],
-                                           prompt.dtype)
-                            prompt = np.concatenate([prompt, pad])
-                        self._prefill_shapes.add(prompt.shape)
-                        args = (self.params, self.cache, self._token,
-                                self._pos, self._active, self._emitted,
-                                self._max_new, self._keys, self._temp,
-                                self._topk, self._topp, jnp.asarray(prompt),
-                                jnp.int32(n), jnp.int32(i),
-                                jnp.int32(req.max_new_tokens),
-                                jnp.int32(len(req.out_tokens) + 1),
-                                jnp.int32(sp.resolve_seed(req.rid)),
-                                jnp.float32(sp.temperature),
-                                jnp.int32(sp.top_k), jnp.float32(sp.top_p))
-                        if self.paged:
-                            args += (pages_arg,)
-                        out = self._dispatch(self._admit_fn, *args)
-                        (self.cache, self._token, self._pos, self._active,
-                         self._emitted, self._max_new, self._keys,
-                         self._temp, self._topk, self._topp, tok0) = out
-                    if self._drafter is not None:
-                        # the drafter mirrors the FULL prompt (generated
-                        # prefix included on recompute re-admission, the
-                        # radix-served prefix included on suffix hits —
-                        # the draft cache has no page sharing), so its
-                        # carry invariant matches the target's exactly
-                        self._drafter.prefill(i, prompt[:n])
-                except RuntimeError as e:
-                    # failure isolation: a faulted prefill (XLA launch /
-                    # runtime error) fails this request alone — its
-                    # admission hold rolls back and the slot refills on
-                    # the next step (deactivated in case the fault hit
-                    # after the target admit already marked it active)
-                    self._active = self._active.at[i].set(False)
-                    self.cm.evict(i)
-                    self._finish(req, "failed", f"prefill fault: {e}")
-                    continue
+
+    def _admit_head(self, i: int, slot: _Slot, req: Request) -> bool:
+        """Admit the head of the queue, ``req``, into the free slot ``i``.
+        False when the pool cannot hold it yet (it stays queued)."""
+        if self.paged and req.swap_state is not None:
+            return self._readmit_swapped(i, slot, req)
+        prompt = np.asarray(req.prompt)
+        if req.out_tokens:
+            # recompute re-admission after preemption: the generated
+            # prefix joins the prompt, so prefill rebuilds the exact
+            # logical cache the victim lost
+            prompt = np.concatenate(
+                [prompt, np.asarray(req.out_tokens, prompt.dtype)])
+        n = len(prompt)
+        b = self._bucket_len(n)
+        if self._prefix_cache:
+            # radix-aware hold: maps the longest cached prefix
+            # read-only + reserves private pages for the rest
+            plan = self.cm.admit_prompt(i, prompt)
+            if plan is None:
+                return False
+        else:
+            plan = None
+            if not self.cm.alloc(i, n):
+                return False
+        self.scheduler.pop()
+        if not req.t_admit:
+            req.t_admit = time.perf_counter()
+        sp = self._sampling_of(req)
+        try:
+            if plan is not None and plan["suffix_start"] > 0:
+                tok0 = self._dispatch_suffix(i, req, prompt, n,
+                                             plan, sp)
+                req.prefix_hit_tokens += plan["suffix_start"]
+            else:
+                pages_arg = None
                 if self.paged:
-                    # the prompt's full pages are now written (prefill
-                    # covers 0..n-1) — publish them to the radix tree so
-                    # later admissions can share them (no-op when disabled)
-                    self.cm.insert_prompt(i, prompt[:n], n)
-                was_requeued = bool(req.out_tokens)
-                req.out_tokens.append(int(tok0))
-                if not req.t_first:
-                    req.t_first = time.perf_counter()
-                if self.paged and was_requeued \
-                        and (len(req.out_tokens) >= req.max_new_tokens
-                             or n >= self.max_seq - 1):
-                    # Recompute re-admission delivered the request's FINAL
-                    # token: in the straight-through run this token came
-                    # from the decode step that fired the stop condition,
-                    # so it must not decode again. (A fresh admission never
-                    # checks — the reference engine always decodes at least
-                    # one step after prefill.)
-                    self._finish(req, "done")
-                    self._active = self._active.at[i].set(False)
-                    self.cm.evict(i)
-                    continue
-                slot.req = req
-                slot.dpos = 1 if self.cfg.family == "encdec" else n
-                slot.demitted = len(req.out_tokens)
-                slot.dactive = True
+                    pages_arg = jnp.asarray(
+                        self.cm.prefill_pages(i, n, b))
+                if b is not None and b > n:
+                    pad = np.zeros((b - n,) + prompt.shape[1:],
+                                   prompt.dtype)
+                    prompt = np.concatenate([prompt, pad])
+                args = (self.params, self.cache, self._token,
+                        self._pos, self._active, self._emitted,
+                        self._max_new, self._keys, self._temp,
+                        self._topk, self._topp, jnp.asarray(prompt),
+                        jnp.int32(n), jnp.int32(i),
+                        jnp.int32(req.max_new_tokens),
+                        jnp.int32(len(req.out_tokens) + 1),
+                        jnp.int32(sp.resolve_seed(req.rid)),
+                        jnp.float32(sp.temperature),
+                        jnp.int32(sp.top_k), jnp.float32(sp.top_p))
+                if self.paged:
+                    args += (pages_arg,)
+                out = self._dispatch(self._admit_fn, *args)
+                (self.cache, self._token, self._pos, self._active,
+                 self._emitted, self._max_new, self._keys,
+                 self._temp, self._topk, self._topp, tok0) = out
+            if self._drafter is not None:
+                # the drafter mirrors the FULL prompt (generated
+                # prefix included on recompute re-admission, the
+                # radix-served prefix included on suffix hits —
+                # the draft cache has no page sharing), so its
+                # carry invariant matches the target's exactly
+                self._drafter.prefill(i, prompt[:n])
+        except RuntimeError as e:
+            # failure isolation: a faulted prefill (XLA launch /
+            # runtime error) fails this request alone — its
+            # admission hold rolls back and the slot refills on
+            # the next step (deactivated in case the fault hit
+            # after the target admit already marked it active)
+            self._active = self._active.at[i].set(False)
+            self.cm.evict(i)
+            self._finish(req, "failed", f"prefill fault: {e}")
+            return True
+        if self.paged:
+            # the prompt's full pages are now written (prefill
+            # covers 0..n-1) — publish them to the radix tree so
+            # later admissions can share them (no-op when disabled)
+            self.cm.insert_prompt(i, prompt[:n], n)
+        was_requeued = bool(req.out_tokens)
+        with jax.profiler.TraceAnnotation("engine.first_token"):
+            req.out_tokens.append(int(tok0))
+        if not req.t_first:
+            req.t_first = time.perf_counter()
+        if self.paged and was_requeued \
+                and (len(req.out_tokens) >= req.max_new_tokens
+                     or n >= self.max_seq - 1):
+            # Recompute re-admission delivered the request's FINAL
+            # token: in the straight-through run this token came
+            # from the decode step that fired the stop condition,
+            # so it must not decode again. (A fresh admission never
+            # checks — the reference engine always decodes at least
+            # one step after prefill.)
+            self._finish(req, "done")
+            self._active = self._active.at[i].set(False)
+            self.cm.evict(i)
+            return True
+        slot.req = req
+        slot.dpos = 1 if self.cfg.family == "encdec" else n
+        slot.demitted = len(req.out_tokens)
+        slot.dactive = True
+        return True
 
     def _dispatch_suffix(self, i: int, req: Request, prompt: np.ndarray,
                          n: int, plan: dict, sp) -> int:
@@ -1081,29 +1097,30 @@ class Engine:
         (capped by its remaining token/sequence budget — the device's
         ``j < budget`` commit gate mirrors exactly this bound, so no
         committed write can ever land on an unbacked page)."""
-        for i in range(self.n_slots):
-            slot = self.slots[i]
-            if slot.req is None or not slot.dactive:
-                continue
-            need = 1
-            if self.spec is not None:
-                budget = min(slot.req.max_new_tokens - slot.demitted,
-                             (self.max_seq - 1) - slot.dpos)
-                need = max(1, min(self.spec.k + 1, budget))
-            while not self.cm.backed(i, slot.dpos + need - 1):
-                if self.cm.grow(i):
+        with jax.profiler.TraceAnnotation("engine.ensure_pages"):
+            for i in range(self.n_slots):
+                slot = self.slots[i]
+                if slot.req is None or not slot.dactive:
                     continue
-                self._drain()
-                if self.slots[i].req is None or not self.slots[i].dactive:
-                    break              # the drain settled this very slot
-                if self.cm.has_free:
-                    continue           # the drain freed finished slots
-                occ = [(j, self.slots[j].req) for j in range(self.n_slots)
-                       if self.slots[j].req is not None]
-                victim = self.preemption.select_victim(occ)
-                self._preempt(victim)
-                if victim == i:
-                    break              # preempted ourselves; requeued
+                need = 1
+                if self.spec is not None:
+                    budget = min(slot.req.max_new_tokens - slot.demitted,
+                                 (self.max_seq - 1) - slot.dpos)
+                    need = max(1, min(self.spec.k + 1, budget))
+                while not self.cm.backed(i, slot.dpos + need - 1):
+                    if self.cm.grow(i):
+                        continue
+                    self._drain()
+                    if self.slots[i].req is None or not self.slots[i].dactive:
+                        break              # the drain settled this very slot
+                    if self.cm.has_free:
+                        continue           # the drain freed finished slots
+                    occ = [(j, self.slots[j].req) for j in range(self.n_slots)
+                           if self.slots[j].req is not None]
+                    victim = self.preemption.select_victim(occ)
+                    self._preempt(victim)
+                    if victim == i:
+                        break              # preempted ourselves; requeued
 
     # -- failure isolation / crash recovery ----------------------------------
 
@@ -1222,6 +1239,11 @@ class Engine:
                     or any(s.req is not None for s in self.slots))
 
     def step(self) -> bool:
+        with jax.profiler.StepTraceAnnotation("engine.step",
+                                              step_num=self._steps):
+            return self._step()
+
+    def _step(self) -> bool:
         step_no = self._steps
         if self.chaos is not None:
             self.chaos.on_step(self, step_no)
@@ -1258,22 +1280,26 @@ class Engine:
                     if self.chaos is not None and self.chaos.relent(self):
                         return True
                 return False
-        args = self._step_args()
-        try:
-            if self.chaos is not None:
-                # BEFORE the draft propose: an injected fault then leaves
-                # the drafter's donated cache unconsumed, exactly like the
-                # target carries
-                self.chaos.pre_dispatch(self, step_no)
-            if self.spec is not None:
-                drafts = self._drafter.propose(self.slots, self._token,
-                                               self._pos)
-                out = self._dispatch(self._spec_step_fn, *args,
-                                     jnp.asarray(drafts))
-            else:
-                out = self._dispatch(self._step_fn, *args)
-        except RuntimeError as e:     # XlaRuntimeError subclasses this
-            self._recover_step_fault(e)
+        fault = None
+        with jax.profiler.TraceAnnotation("engine.dispatch"):
+            args = self._step_args()
+            try:
+                if self.chaos is not None:
+                    # BEFORE the draft propose: an injected fault then
+                    # leaves the drafter's donated cache unconsumed,
+                    # exactly like the target carries
+                    self.chaos.pre_dispatch(self, step_no)
+                if self.spec is not None:
+                    drafts = self._drafter.propose(self.slots, self._token,
+                                                   self._pos)
+                    out = self._dispatch(self._spec_step_fn, *args,
+                                         jnp.asarray(drafts))
+                else:
+                    out = self._dispatch(self._step_fn, *args)
+            except RuntimeError as e:  # XlaRuntimeError subclasses this
+                fault = e
+        if fault is not None:
+            self._recover_step_fault(fault)
             return True
         (self.cache, self._token, self._pos, self._active,
          self._emitted, self._keys, emit) = out
@@ -1346,8 +1372,9 @@ class Engine:
         # replicated by construction, so no extra per-shard transfers).
         # Counted so the bench CI can gate one-readback-per-step exactly.
         self._readbacks += 1
-        tok = np.asarray(emit_tok)
-        fin = np.asarray(done)
+        with jax.profiler.TraceAnnotation("engine.readback"):
+            tok = np.asarray(emit_tok)
+            fin = np.asarray(done)
         for i, req in enumerate(reqs):
             if req is None or req.done or tok[i] == -1:
                 # ``req.done``: a request quarantined by the corrupt-
@@ -1400,8 +1427,9 @@ class Engine:
         ordering matches target-only decoding bit for bit."""
         (emit_tok, done), reqs = pending
         self._readbacks += 1
-        tok = np.asarray(emit_tok)
-        fin = np.asarray(done)
+        with jax.profiler.TraceAnnotation("engine.readback"):
+            tok = np.asarray(emit_tok)
+            fin = np.asarray(done)
         for i, req in enumerate(reqs):
             if req is None or req.done:
                 continue
@@ -1473,7 +1501,6 @@ class Engine:
             "steps": self._steps,
             "readbacks": self._readbacks,
             "prefill_compiles": int(prefill_compiles),
-            "prefill_shapes": sorted(s[0] for s in self._prefill_shapes),
             "suffix_shapes": sorted(self._suffix_shapes),
             "pad_prefill": self._pad_ok,
             "slots": self.n_slots,

@@ -181,17 +181,6 @@ class PagePool:
         for page in pages:
             self.drop(page)
 
-    def stats(self) -> dict:
-        """Occupancy snapshot (consumed by the paged ``CacheManager``)."""
-        n_shared = sum(1 for p in range(1, self.num_pages + 1)
-                       if self.refcnt[p] - self._ext[p] >= 2
-                       or (self._ext[p] and self.refcnt[p] > self._ext[p]))
-        return {"num_pages": self.num_pages,
-                "pages_in_use": self.pages_in_use,
-                "num_free": self.num_free,
-                "pages_shared": n_shared,
-                "tree_refs": sum(self._ext)}
-
     # -- invariants ---------------------------------------------------------
 
     def check(self) -> None:
