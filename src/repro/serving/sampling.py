@@ -85,6 +85,12 @@ def sample_tokens(logits, keys, index, temperature, top_k, top_p):
     computation of the historical greedy engine, so greedy streams stay
     bit-identical. Non-greedy rows apply top-k then top-p truncation and
     draw via the Gumbel-argmax trick (an exact categorical sample).
+
+    The rule: order the scaled logits descending, ties by vocab id (a
+    stable sort); keep the first ``top_k`` (all when 0); of those, keep
+    each token whose preceding softmax mass is below ``top_p``; draw the
+    kept token with the largest scaled logit plus its Gumbel noise, the
+    noise drawn in vocab order, ties to the lowest vocab id.
     """
     vocab = logits.shape[-1]
     # Materialize the logits ONCE before they fan out to the argmax and
@@ -98,19 +104,24 @@ def sample_tokens(logits, keys, index, temperature, top_k, top_p):
     def one(lg, key, idx, temp, k, p):
         greedy_tok = jnp.argmax(lg).astype(jnp.int32)
         scaled = lg.astype(jnp.float32) / jnp.maximum(temp, 1e-6)
-        order = jnp.argsort(-scaled)           # descending logit order
-        ranks = jnp.argsort(order)             # rank of each vocab entry
+        g = jax.random.gumbel(jax.random.fold_in(key, idx), (vocab,))
+        # one stable sort (descending logit order, the order of
+        # ``argsort(-scaled)``) carries each entry's vocab id and noise
+        # along, so truncation and draw stay in sorted order: an
+        # element-wise gather over the vocab is slow on the TPU
+        neg, ids, g_sorted = jax.lax.sort(
+            (-scaled, jnp.arange(vocab, dtype=jnp.int32), g),
+            num_keys=1, is_stable=True)
         k_eff = jnp.where(k > 0, k, vocab)
-        keep_k = ranks < k_eff
-        probs = jax.nn.softmax(jnp.where(keep_k, scaled, -jnp.inf))
-        sorted_probs = probs[order]
-        cum = jnp.cumsum(sorted_probs)
+        keep_k = jnp.arange(vocab) < k_eff
+        probs = jax.nn.softmax(jnp.where(keep_k, -neg, -jnp.inf))
         # keep tokens whose PRECEDING cumulative mass is < p: the top token
         # always survives, and the token that crosses p is included
-        keep_p = ((cum - sorted_probs) < p)[ranks]
-        final = jnp.where(keep_k & keep_p, scaled, -jnp.inf)
-        g = jax.random.gumbel(jax.random.fold_in(key, idx), (vocab,))
-        sampled = jnp.argmax(final + g).astype(jnp.int32)
+        keep_p = (jnp.cumsum(probs) - probs) < p
+        score = jnp.where(keep_k & keep_p, -neg, -jnp.inf) + g_sorted
+        # the lowest vocab id among the best scores: argmax's tie-break
+        # in vocab order, read by a masked reduction rather than a gather
+        sampled = jnp.min(jnp.where(score == jnp.max(score), ids, vocab))
         return jnp.where(temp <= 0.0, greedy_tok, sampled)
 
     return jax.vmap(one)(logits, keys, index, temperature, top_k, top_p)

@@ -6,6 +6,8 @@ LLMEngine generate/stream facade, the deprecation shims for the old
 Engine kwargs, and the one-batched-readback-per-step invariant for
 non-greedy decode (sampling must add zero extra host syncs)."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -97,6 +99,115 @@ def test_sample_tokens_reduces_to_argmax():
     other = sample_tokens(lg, keys, idx + 1, 5.0 * ones,
                           jnp.full((6,), k, jnp.int32), ones)
     assert (np.asarray(topk) != np.asarray(other)).any()
+
+
+ROWS, VOCAB = 64, 4096
+_draw = jax.jit(sample_tokens)
+
+
+def _oracle(lg, keys, index, temp, top_k, top_p):
+    """``sample_tokens``'s documented rule in NumPy, masses in float64:
+    stable descending order, the top-k prefix, top-p on the preceding
+    mass, Gumbel-argmax with the noise drawn in vocab order (ties to the
+    lowest id). Asserts that no preceding mass lies within float32
+    rounding of ``top_p``, so that the case's answer is well defined."""
+    lg = np.asarray(lg.astype(jnp.float32))
+    out = []
+    for b in range(lg.shape[0]):
+        if temp[b] <= 0.0:
+            out.append(int(np.argmax(lg[b])))
+            continue
+        scaled = lg[b] / np.maximum(np.float32(temp[b]), np.float32(1e-6))
+        kept = np.argsort(-scaled, kind="stable")[:top_k[b] or VOCAB]
+        s = scaled[kept].astype(np.float64)
+        probs = np.exp(s - s.max())
+        probs /= probs.sum()
+        prev = np.cumsum(probs) - probs
+        # at top_p 1.0 only a tail whose preceding mass rounds up to 1
+        # can differ, and it holds far too little mass to be drawn
+        margin = np.abs(prev[1:] - top_p[b]).min(initial=1.0)
+        assert top_p[b] >= 1.0 or margin > 2e-6
+        kept = kept[prev < top_p[b]]
+        g = np.asarray(jax.random.gumbel(
+            jax.random.fold_in(keys[b], index[b]), (VOCAB,)))
+        score = scaled[kept] + g[kept]
+        out.append(int(kept[score == score.max()].min()))
+    return np.asarray(out)
+
+
+def _tied_rows(rng, n_rows, values, tail):
+    """bf16 rows whose leading entries take ``values`` (equal values are
+    exact ties) at random vocab ids, the rest ``tail`` below them."""
+    lg = np.full((n_rows, VOCAB), tail, np.float32)
+    lg += 0.25 * rng.standard_normal((n_rows, VOCAB)).astype(np.float32)
+    for row in lg:
+        row[rng.choice(VOCAB, len(values), replace=False)] = values
+    return lg
+
+
+def _sampling_case(name):
+    """(bf16 logits, temperature, top_k, top_p) of one equivalence case."""
+    rng = np.random.default_rng(sum(map(ord, name)))
+    lg = 3.0 * rng.standard_normal((ROWS, VOCAB)).astype(np.float32)
+    temp = np.full(ROWS, 0.8, np.float32)
+    k = np.zeros(ROWS, np.int32)
+    p = np.ones(ROWS, np.float32)
+    if name == "greedy":
+        temp[:] = 0.0
+        k[:], p[:] = 1, 1e-9          # ignored by greedy rows
+        lg[:, [70, 3000]] = lg.max() + 1.0    # exact tie at the argmax
+    elif name.startswith("top_k"):
+        k[:] = int(name[5:])
+    elif name.startswith("top_p"):
+        p[:] = float(name[5:])
+    elif name == "tie_at_top_k":
+        # k=1 rows: two tokens tie for the top; k=20 rows: 21 tie, so
+        # the 20th and 21st places are a tie across the cut
+        half = ROWS // 2
+        lg[:half] = _tied_rows(rng, half, [3.0, 3.0], -4.0)
+        lg[half:] = _tied_rows(rng, ROWS - half, [3.0] * 21, -4.0)
+        temp[:], k[:half], k[half:] = 1.0, 1, 20
+    elif name == "tie_at_top_p":
+        # masses 0.55, 0.30, then a tied pair of 0.07 each: the first of
+        # the pair (lower id) starts below 0.9, the second above it
+        lg = _tied_rows(rng, ROWS, np.log([0.55, 0.30, 0.07, 0.07]), -13.0)
+        temp[:], p[:] = 1.0, 0.9
+    elif name == "mixed":
+        temp = rng.choice([0.0, 0.5, 0.8, 1.3], ROWS).astype(np.float32)
+        k = rng.choice([0, 1, 20], ROWS).astype(np.int32)
+        p = rng.choice([1.0, 0.9, 1e-9], ROWS).astype(np.float32)
+    return jnp.asarray(lg).astype(jnp.bfloat16), temp, k, p
+
+
+@pytest.mark.parametrize("name", [
+    "greedy", "top_k0", "top_k1", "top_k20", "top_p1.0", "top_p0.9",
+    "top_p1e-9", "tie_at_top_k", "tie_at_top_p", "mixed"])
+def test_sample_tokens_matches_numpy_oracle(name):
+    """Every row draws the token the documented rule gives, bf16 ties
+    at the top-k and top-p cuts included."""
+    lg, temp, k, p = _sampling_case(name)
+    keys = jnp.stack([jax.random.PRNGKey(1000 + r) for r in range(ROWS)])
+    index = jnp.arange(ROWS, dtype=jnp.int32) * 7
+    got = _draw(lg, keys, index, jnp.asarray(temp), jnp.asarray(k),
+                jnp.asarray(p))
+    np.testing.assert_array_equal(
+        np.asarray(got), _oracle(lg, np.asarray(keys), np.asarray(index),
+                                 temp, k, p))
+
+
+def test_sample_tokens_compiles_to_one_sort_and_no_gather():
+    """At the served shape (32 slots over qwen2's 151,936-entry vocab,
+    bf16) the compiled draw holds one sort and no gather: truncation and
+    draw run in sorted order, and an element-wise gather over the vocab
+    is slow on the TPU."""
+    S = jax.ShapeDtypeStruct
+    b, v = 32, 151936
+    hlo = jax.jit(sample_tokens).lower(
+        S((b, v), jnp.bfloat16), S((b, 2), jnp.uint32), S((b,), jnp.int32),
+        S((b,), jnp.float32), S((b,), jnp.int32), S((b,), jnp.float32),
+    ).compile().as_text()
+    assert len(re.findall(r"(?<=\s)gather\(", hlo)) == 0
+    assert len(re.findall(r"(?<=\s)sort\(", hlo)) == 1
 
 
 # ---------------------------------------------------------------------------
