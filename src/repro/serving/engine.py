@@ -231,7 +231,7 @@ class Engine:
             # round-trips a consistent committed sharding
             self.params = tp.shard_params(params, cfg, self._plan)
             self._pspecs = tp.param_specs(self.params, self._plan)
-        self.cache = self._put_cache(self.cm.init())
+        self.cache = self._new_cache()
         self.chaos = None
         if chaos is not None:
             self.chaos = chaos if hasattr(chaos, "on_step") \
@@ -331,11 +331,13 @@ class Engine:
         """Replicate a carry buffer on the mesh (identity off-mesh)."""
         return x if self._plan is None else tp.replicate(x, self._plan)
 
-    def _put_cache(self, cache):
-        """Place a fresh KV pool on the mesh (kv_heads over ``model``
-        when the plan shards heads; identity off-mesh)."""
-        return cache if self._plan is None \
-            else tp.put_cache(cache, self._plan)
+    def _new_cache(self):
+        """A fresh KV pool: on the mesh, built straight into its sharding
+        (kv_heads over ``model`` when the plan shards heads), so no
+        device ever holds the whole pool; off-mesh, the cache manager's
+        zero-fill on the default device."""
+        return self.cm.init() if self._plan is None \
+            else tp.make_cache(self.cm.init, self._plan)
 
     def _fresh_carries(self) -> None:
         """(Re)build the nine per-slot carry buffers as zeros — shared by
@@ -1222,7 +1224,7 @@ class Engine:
             self.cm.pool.check()
         # rebuild the device-side state (same shapes and shardings: no
         # retrace, and mesh placements survive the recovery)
-        self.cache = self._put_cache(self.cm.init())
+        self.cache = self._new_cache()
         self._fresh_carries()
         if self._drafter is not None:
             # the draft cache shares the device that faulted: drop it and
@@ -1491,8 +1493,9 @@ class Engine:
 
     def stats(self) -> dict:
         """Decode steps, prefill retrace count, bucket coverage, scheduler
-        counters, and (paged) preemption + page-pool utilization/
-        fragmentation."""
+        counters, the mesh plan (``describe()``), the KV pool's bytes on
+        each device (``kv_pool_bytes_per_chip``: one shard on a mesh),
+        and (paged) preemption + page-pool utilization/fragmentation."""
         prefill_compiles = self._compiles_base \
             + self._admit_fn._cache_size()
         if self._prefix_cache:
@@ -1531,6 +1534,9 @@ class Engine:
                 accepted / draft_tokens if draft_tokens else 0.0
         if self._plan is not None:
             out["mesh"] = self._plan.describe()
+        out["kv_pool_bytes_per_chip"] = int(sum(
+            np.prod(x.sharding.shard_shape(x.shape)) * x.dtype.itemsize
+            for x in jax.tree.leaves(self.cache)))
         if self.chaos is not None:
             out.update(self.chaos.stats())
         if self.paged:
