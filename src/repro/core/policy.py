@@ -101,7 +101,7 @@ class PolicyBackend:
             for knob in space.knobs:
                 if knob.kind != "pow2" or term not in knob.attacks:
                     continue
-                cur = getattr(variant, knob.name)
+                cur = knob.value(variant)
                 for val, why in ((cur * 2, "grow"), (cur // 2, "shrink")):
                     if knob.lo <= val <= knob.hi:
                         add(Suggestion(knob.name, val,
@@ -111,7 +111,7 @@ class PolicyBackend:
     # -- helpers -----------------------------------------------------------
 
     def _move(self, space, variant, knob: Knob, profile: Profile):
-        cur = getattr(variant, knob.name)
+        cur = knob.value(variant)
         if knob.kind == "bool":
             # Only move toward the catalog-optimized direction; a knob whose
             # current value already sits at the target offers no move.

@@ -86,7 +86,7 @@ def optimize_single_agent(kernel: str | KernelSpace, *, rounds: int = 5,
             # has no transformation catalog telling it the good direction
             value = not getattr(s_prev, name)
         else:
-            value = min(knob.hi, getattr(s_prev, name) * 2)
+            value = min(knob.hi, knob.value(s_prev) * 2)
         sugg = Suggestion(name, value, f"checklist: try {name}={value}")
         s_new = space.mutate(s_prev, knob, value)
         pass_new, max_err = tester.validate(space, s_new, quick)

@@ -30,13 +30,19 @@ Variant knobs (the space Astra searches):
 **Paged form** (``paged_flash_decode_attention``): the production layout of
 this kernel in a paged-KV serving engine. K/V live in a global page pool
 ``[num_pages, page_size, kv_heads, head_dim]`` shared by all requests; a
-per-request page table maps logical page ``j`` to its physical page.  The
-grid's sequential axis walks *logical* pages and the K/V BlockSpec
-index_maps read the scalar-prefetched page table to DMA the right physical
-block — the same online-softmax carry, with the gather folded into the
-block fetch.  ``page_size`` is a search knob of its own registered space
-(``paged_flash_decode``): it sets both the pool granule the serving engine
-allocates in and this kernel's per-step working set.
+per-request page table maps logical page ``j`` to its physical page. The
+grid is one step per slot. Inside it a ``fori_loop`` walks only the slot's
+live blocks (``ceil(kv_len / block rows)``, from the scalar-prefetched
+``kv_len``); a block is ``pages_per_block`` pages, each DMA'd whole (all
+its KV heads, contiguous) from the pool through the scalar-prefetched table
+into one of two VMEM buffers, so the next block (or the next slot's first)
+loads while this one computes. All KV heads of a slot share the fetched
+pages: a block-diagonal query scores each head against its own lanes. The
+same online-softmax carry runs per block. ``page_size`` and
+``pages_per_block`` are search knobs of its registered space
+(``paged_flash_decode``): the first sets the pool granule the serving
+engine allocates in, the second the per-block working set (by default
+``BLOCK_ROWS`` rows, at most ``BLOCK_BYTES`` of K).
 """
 
 from __future__ import annotations
@@ -107,14 +113,14 @@ def _online_softmax_step(q, k, v, acc_ref, m_ref, l_ref, *,
     l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
 
 
-def _finalize_output(o_ref, acc_ref, l_ref, *, use_reciprocal):
+def _finalize_output(acc_ref, l_ref, *, use_reciprocal):
+    """The normalized carry (float32); zeros where nothing was attended."""
     l = l_ref[...][:, :1]
     if use_reciprocal:
         inv = jnp.where(l > 0, pl.reciprocal(l, approx=False), 0.0)
-        o_ref[0] = (acc_ref[...] * inv).astype(o_ref.dtype)
-    else:
-        safe_l = jnp.where(l > 0, l, 1.0)
-        o_ref[0] = (acc_ref[...] / safe_l).astype(o_ref.dtype)
+        return acc_ref[...] * inv
+    safe_l = jnp.where(l > 0, l, 1.0)
+    return acc_ref[...] / safe_l
 
 
 def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref,
@@ -144,7 +150,8 @@ def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(j == n_chunks - 1)
     def _finalize():
-        _finalize_output(o_ref, acc_ref, l_ref, use_reciprocal=use_reciprocal)
+        o_ref[0] = _finalize_output(
+            acc_ref, l_ref, use_reciprocal=use_reciprocal).astype(o_ref.dtype)
 
 
 def flash_decode_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
@@ -339,55 +346,145 @@ def _space() -> KernelSpace:
 @dataclasses.dataclass(frozen=True)
 class PagedFlashDecodeVariant:
     """Knobs of the paged kernel. ``page_size`` is the pool granule: the
-    serving engine allocates KV in ``page_size``-row pages and this kernel
-    processes one page per sequential grid step (the paged analogue of
-    ``chunk``). At apply time the kernel reads the page size off the pool's
-    shape; the knob steers the *search*, whose verdict sizes the pool."""
+    serving engine allocates KV in ``page_size``-row pages. At apply time
+    the kernel reads the page size off the pool's shape; the knob steers
+    the *search*, whose verdict sizes the pool. ``pages_per_block`` is how
+    many pages one compute block fetches (``None``: the shape rule of
+    ``block_pages``)."""
     name: str = "baseline"
     page_size: int = 16
     use_reciprocal: bool = False
     mask_oob: bool = False
+    pages_per_block: int | None = None
 
     def describe(self) -> str:
         return (f"{self.name}: page_size={self.page_size} "
-                f"rcp={self.use_reciprocal} mask_oob={self.mask_oob}")
+                f"rcp={self.use_reciprocal} mask_oob={self.mask_oob} "
+                f"pages_per_block={self.pages_per_block}")
 
 
 PAGED_BASELINE = PagedFlashDecodeVariant()
 PAGED_OPTIMIZED = PagedFlashDecodeVariant(name="astra_opt", page_size=64,
                                           use_reciprocal=True, mask_oob=True)
 
+BLOCK_ROWS = 1024           # KV rows one compute block covers ...
+BLOCK_BYTES = 512 * 1024    # ... in at most this many bytes of K
+LANES = 128
 
-def _paged_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-                  acc_ref, m_ref, l_ref, *,
-                  page, hkv, sm_scale, use_reciprocal, mask_oob):
-    i = pl.program_id(0)                  # batch * kv_head
-    j = pl.program_id(1)                  # LOGICAL page index
-    n_pages = pl.num_programs(1)
-    kv_len = len_ref[i // hkv]
 
-    @pl.when(j == 0)
-    def _init():
-        _init_carry(acc_ref, m_ref, l_ref)
+def block_pages(pages_per_block: int | None, page: int, page_bytes: int,
+                n_pt: int) -> int:
+    """Pages one compute block fetches: ``pages_per_block``, or by the
+    shape rule ``BLOCK_ROWS`` rows in at most ``BLOCK_BYTES`` of K;
+    capped at the table."""
+    if pages_per_block is None:
+        pages_per_block = min(BLOCK_ROWS // page, BLOCK_BYTES // page_bytes)
+    return max(1, min(pages_per_block, n_pt))
 
-    def _step():
-        _online_softmax_step(
-            q_ref[0].astype(jnp.float32),
-            k_ref[0, 0].astype(jnp.float32),          # [page, D]
-            v_ref[0, 0].astype(jnp.float32),
-            acc_ref, m_ref, l_ref,
-            pos0=j * page, kv_len=kv_len, sm_scale=sm_scale)
 
-    if mask_oob:
-        # skip logical pages entirely past kv_len (their physical blocks
-        # may belong to other requests — never read, never computed)
-        pl.when(j * page < kv_len)(_step)
-    else:
-        _step()
+def heads_per_chunk(kv_heads: int, head_dim: int) -> int:
+    """KV heads that share one lane-aligned chunk of a page row. A chunk's
+    heads are attended together by one block-diagonal query: narrow heads
+    (``head_dim`` 64) pair up in 128 lanes, wide ones go one by one."""
+    hpc = min(kv_heads, max(1, LANES // head_dim))
+    if kv_heads % hpc or (hpc < kv_heads and hpc * head_dim % LANES):
+        return kv_heads             # no aligned split: one chunk of all
+    return hpc
 
-    @pl.when(j == n_pages - 1)
-    def _finalize():
-        _finalize_output(o_ref, acc_ref, l_ref, use_reciprocal=use_reciprocal)
+
+def _paged_kernel(pt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
+                  k_buf, v_buf, sems, base_ref, acc_ref, m_ref, l_ref, *,
+                  page, ppb, n_pt, n_chunks, width, sm_scale,
+                  use_reciprocal, mask_oob):
+    """One grid step is one slot. A ``fori_loop`` walks the slot's blocks
+    of ``ppb`` pages, each page DMA'd from the pool through the table into
+    one of two VMEM buffers: block ``i + 1`` (or the next slot's first
+    block) loads while block ``i`` computes. ``base_ref`` carries the
+    buffer of the slot's first block across grid steps."""
+    b = pl.program_id(0)
+    rows = ppb * page
+
+    def length(s):
+        return jnp.minimum(len_ref[s], n_pt * page)
+
+    def walk(s):                    # rows the slot's walk covers
+        return length(s) if mask_oob else n_pt * page
+
+    def copy_block(s, blk, buf, wait):
+        """Start (or wait for) the K and V copies of block ``blk`` of slot
+        ``s`` into buffer ``buf``: one per page, through the table; pages
+        at or past the walk are neither copied nor waited for. A loop, so
+        the kernel's size does not grow with ``ppb``."""
+        first = blk * ppb
+
+        def one(i, carry):
+            phys = pt_ref[s * n_pt + first + i]
+            for hbm, vmem, sem in ((k_hbm, k_buf, sems.at[0, buf]),
+                                   (v_hbm, v_buf, sems.at[1, buf])):
+                cp = pltpu.make_async_copy(hbm.at[phys], vmem.at[buf, i], sem)
+                cp.wait() if wait else cp.start()
+            return carry
+
+        live = jnp.clip((walk(s) + page - 1) // page - first, 0, ppb)
+        jax.lax.fori_loop(0, live, one, 0)
+
+    def start(s, blk, buf):
+        copy_block(s, blk, buf, wait=False)
+
+    def wait(s, blk, buf):
+        copy_block(s, blk, buf, wait=True)
+
+    has_next = b + 1 < pl.num_programs(0)
+    n_blk = (walk(b) + rows - 1) // rows
+
+    @pl.when(b == 0)
+    def _first():
+        base_ref[0] = 0
+        start(0, 0, 0)
+
+    base = base_ref[0]
+    for c in range(n_chunks):
+        _init_carry(acc_ref.at[c], m_ref.at[c], l_ref.at[c])
+
+    def body(blk, carry):
+        buf = (base + blk) % 2
+
+        @pl.when(blk + 1 < n_blk)
+        def _():
+            start(b, blk + 1, 1 - buf)
+
+        @pl.when((blk + 1 == n_blk) & has_next)
+        def _():
+            start(b + 1, 0, 1 - buf)
+
+        wait(b, blk, buf)
+        pos0 = blk * rows
+        kv_len = length(b)
+        k = k_buf[buf].astype(jnp.float32).reshape(rows, n_chunks * width)
+        v = v_buf[buf].astype(jnp.float32).reshape(rows, n_chunks * width)
+        # rows past the length hold a trap page or a stale buffer: their
+        # scores are masked, and zeroing them keeps 0 * garbage out of p @ v
+        live = pos0 + jax.lax.broadcasted_iota(jnp.int32, v.shape, 0)
+        v = jnp.where(live < kv_len, v, 0.0)
+        for c in range(n_chunks):
+            lanes = slice(c * width, (c + 1) * width)
+            _online_softmax_step(
+                q_ref[0, c].astype(jnp.float32), k[:, lanes], v[:, lanes],
+                acc_ref.at[c], m_ref.at[c], l_ref.at[c],
+                pos0=pos0, kv_len=kv_len, sm_scale=sm_scale)
+        return carry
+
+    jax.lax.fori_loop(0, n_blk, body, 0)
+
+    @pl.when((n_blk == 0) & has_next)
+    def _():
+        start(b + 1, 0, base)       # an empty slot hands its buffer on
+
+    base_ref[0] = (base + n_blk) % 2
+    for c in range(n_chunks):
+        o_ref[0, c] = _finalize_output(
+            acc_ref.at[c], l_ref.at[c],
+            use_reciprocal=use_reciprocal).astype(o_ref.dtype)
 
 
 def paged_flash_decode_attention(q: jax.Array, k_pages: jax.Array,
@@ -405,15 +502,16 @@ def paged_flash_decode_attention(q: jax.Array, k_pages: jax.Array,
         global block pool (shared by every request).
       page_table: ``[batch, pages_per_seq]`` int32 — logical page ``j`` of
         request ``b`` lives in physical page ``page_table[b, j]``.
-      kv_len: ``[batch]`` int32 valid lengths (default: the full table).
+      kv_len: ``[batch]`` int32 valid lengths (default: the full table;
+        longer ones are clamped to it). A length of 0 gives zeros.
 
-    Returns ``[batch, q_heads, head_dim]``; bitwise it computes attention
-    over the gathered cache ``k_pages[page_table]`` — table entries at or
-    past ``kv_len`` may point anywhere valid (the engine points them at a
-    trap page) and are fully masked.
+    Returns ``[batch, q_heads, head_dim]``: attention over the gathered
+    cache ``k_pages[page_table]`` — table entries at or past ``kv_len``
+    may point anywhere valid (the engine points them at a trap page) and
+    are fully masked. With ``mask_oob`` a slot's walk stops at its length.
     """
     b, hq, dh = q.shape
-    _, page, hkv, _ = k_pages.shape
+    n_p, page, hkv, _ = k_pages.shape
     n_pt = page_table.shape[1]
     group = hq // hkv
     if sm_scale is None:
@@ -421,64 +519,103 @@ def paged_flash_decode_attention(q: jax.Array, k_pages: jax.Array,
     if kv_len is None:
         kv_len = jnp.full((b,), n_pt * page, jnp.int32)
 
+    hpc = heads_per_chunk(hkv, dh)
+    n_chunks, width = hkv // hpc, hpc * dh
     g_pad = round_up(group, 8)
-    q4 = q.reshape(b, hkv, group, dh)
+    # block-diagonal query: row h * g_pad + g of a chunk holds head h's
+    # query g in head h's lanes and zeros elsewhere, so one matmul over
+    # the chunk's lanes scores every head of the chunk against its own K
+    q5 = q.reshape(b, n_chunks, hpc, group, dh)
     if g_pad != group:
-        q4 = jnp.pad(q4, ((0, 0), (0, 0), (0, g_pad - group), (0, 0)))
-    q3 = q4.reshape(b * hkv, g_pad, dh)
-    # [P, hkv, page, dh]: one (physical page, head) pair per block fetch
-    k4 = jnp.swapaxes(k_pages, 1, 2)
-    v4 = jnp.swapaxes(v_pages, 1, 2)
+        q5 = jnp.pad(q5, ((0, 0),) * 3 + ((0, g_pad - group), (0, 0)))
+    eye = jnp.eye(hpc, dtype=bool)[:, None, :, None]
+    q_bd = jnp.where(eye, q5[:, :, :, :, None, :], jnp.zeros((), q.dtype))
+    q_bd = q_bd.reshape(b, n_chunks, hpc * g_pad, width)
+    # a page's rows are contiguous across heads: one DMA fetches it whole
+    k3 = k_pages.reshape(n_p, page, hkv * dh)
+    v3 = v_pages.reshape(n_p, page, hkv * dh)
     flat_pt = page_table.reshape(-1).astype(jnp.int32)   # [b * n_pt]
+    ppb = block_pages(variant.pages_per_block, page,
+                      page * hkv * dh * k_pages.dtype.itemsize, n_pt)
 
     kern = functools.partial(
-        _paged_kernel, page=page, hkv=hkv, sm_scale=sm_scale,
+        _paged_kernel, page=page, ppb=ppb, n_pt=n_pt, n_chunks=n_chunks,
+        width=width, sm_scale=sm_scale,
         use_reciprocal=variant.use_reciprocal, mask_oob=variant.mask_oob)
-
-    def kv_map(i, j, pt_ref, len_ref):
-        # gather through the scalar-prefetched table: logical page j of
-        # request i // hkv -> physical block index
-        return (pt_ref[(i // hkv) * n_pt + j], i % hkv, 0, 0)
-
+    q_spec = pl.BlockSpec((1, n_chunks, hpc * g_pad, width),
+                          lambda i, pt, ln: (i, 0, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,            # page table + kv_len
-        grid=(b * hkv, n_pt),
-        in_specs=[
-            pl.BlockSpec((1, g_pad, dh), lambda i, j, pt, ln: (i, 0, 0)),
-            pl.BlockSpec((1, 1, page, dh), kv_map),
-            pl.BlockSpec((1, 1, page, dh), kv_map),
-        ],
-        out_specs=pl.BlockSpec((1, g_pad, dh), lambda i, j, pt, ln: (i, 0, 0)),
+        grid=(b,),
+        in_specs=[q_spec, pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=q_spec,
         scratch_shapes=[
-            pltpu.VMEM((g_pad, dh), jnp.float32),
-            pltpu.VMEM((g_pad, 128), jnp.float32),
-            pltpu.VMEM((g_pad, 128), jnp.float32),
+            pltpu.VMEM((2, ppb, page, hkv * dh), k_pages.dtype),
+            pltpu.VMEM((2, ppb, page, hkv * dh), v_pages.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.SMEM((1,), jnp.int32),
+            pltpu.VMEM((n_chunks, hpc * g_pad, width), jnp.float32),
+            pltpu.VMEM((n_chunks, hpc * g_pad, 128), jnp.float32),
+            pltpu.VMEM((n_chunks, hpc * g_pad, 128), jnp.float32),
         ],
     )
     out = pl.pallas_call(
         kern, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b * hkv, g_pad, dh), q.dtype),
+        out_shape=jax.ShapeDtypeStruct(q_bd.shape, q.dtype),
+        # sequential slots: a slot prefetches the next one's first block
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(flat_pt, kv_len.astype(jnp.int32), q3, k4, v4)
+    )(flat_pt, kv_len.astype(jnp.int32), q_bd, k3, v3)
 
-    return out.reshape(b, hkv, g_pad, dh)[:, :, :group].reshape(b, hq, dh)
+    out = out.reshape(b, n_chunks, hpc, g_pad, hpc, dh)
+    out = jnp.moveaxis(jnp.diagonal(out, axis1=2, axis2=4), -1, 2)
+    return out[:, :, :, :group].reshape(b, hq, dh)
 
 
 def paged_cost(variant: PagedFlashDecodeVariant, *, batch: int, q_heads: int,
                kv_heads: int, head_dim: int, seq: int, dtype,
                mean_kv_len: float | None = None):
-    """Analytic cost: the split-KV cost at chunk=page_size plus the page
-    table reads (SMEM-prefetched, but they still cross HBM once)."""
-    proxy = FlashDecodeVariant(chunk=variant.page_size,
-                               use_reciprocal=variant.use_reciprocal,
-                               mask_oob=variant.mask_oob)
-    c = cost(proxy, batch=batch, q_heads=q_heads, kv_heads=kv_heads,
-             head_dim=head_dim, seq=seq, dtype=dtype,
-             mean_kv_len=mean_kv_len)
-    n_pt = round_up(seq, variant.page_size) // variant.page_size
-    c = dataclasses.replace(c, hbm_bytes=c.hbm_bytes + batch * n_pt * 4)
+    """Analytic cost of the slot-grid kernel: one grid step per slot, two
+    page DMAs (K and V) per page walked, each issued by the scalar core
+    like a grid step; the table crosses HBM once."""
+    from repro.core import costmodel as cm
+
+    item = jnp.dtype(dtype).itemsize
+    page = variant.page_size
+    n_pt = round_up(seq, page) // page
+    walked = n_pt
+    if variant.mask_oob and mean_kv_len is not None:
+        walked = min(n_pt, -(-int(mean_kv_len) // page))
+    ppb = block_pages(variant.pages_per_block, page,
+                      page * kv_heads * head_dim * item, n_pt)
+    hpc = heads_per_chunk(kv_heads, head_dim)
+    rows_q = kv_heads * round_up(q_heads // kv_heads, 8)   # all chunks
+    rows = -(-walked // ppb) * ppb * page                  # rows computed
+    dmas = 2 * batch * walked
+    ops = cm.OP
+
+    kv_bytes = dmas * page * kv_heads * head_dim * item
+    q_bytes = batch * q_heads * head_dim * item
+    o_bytes = batch * rows_q * hpc * head_dim * item
+    mxu = 2 * 2 * batch * rows_q * hpc * head_dim * rows     # qk + pv
+    vpu = batch * rows_q * rows * (
+        ops["exp"] + 2 * ops["cmp"] + ops["max"] + 2 * ops["fma"])
+    vpu += batch * rows * kv_heads * head_dim * 2 * ops["cast"]
+    vpu += batch * rows_q * hpc * head_dim * (
+        (ops["rcp"] + ops["mul"]) if variant.use_reciprocal else ops["div"])
+    c = cm.Cost(
+        hbm_bytes=kv_bytes + q_bytes + o_bytes + batch * n_pt * 4,
+        vpu_ops=vpu,
+        mxu_flops=mxu,
+        mxu_dtype="fp32",
+        grid_steps=batch + dmas,
+        n_calls=1,
+        vmem_bytes=(2 * 2 * ppb * page * kv_heads * head_dim * item
+                    + 2 * rows_q * hpc * head_dim * 4      # q, acc
+                    + 2 * rows_q * 128 * 4),               # m, l
+    )
     c.validate()
     return c
 
@@ -530,12 +667,19 @@ def _paged_space() -> KernelSpace:
         knobs=(
             Knob("page_size", "pow2", 8, 256,
                  attacks=("overhead", "memory"),
-                 note="KV pool granule = rows per grid step; small pages "
+                 note="KV pool granule = rows per page DMA; small pages "
                       "cut allocator fragmentation, large pages cut "
-                      "grid/DMA overhead"),
+                      "DMA issue overhead"),
+            Knob("pages_per_block", "pow2", 1, 64, attacks=("overhead",),
+                 note="pages one compute block fetches (None: "
+                      f"{BLOCK_ROWS} rows, at most {BLOCK_BYTES // 1024} "
+                      "KiB of K, a block); large blocks cut per-block "
+                      "overhead, small ones the rows computed past a "
+                      "slot's length"),
             Knob("mask_oob", "bool", attacks=("memory", "compute"),
                  target=True,
-                 note="predicate logical pages past kv_len"),
+                 note="stop each slot's walk at kv_len (baseline: "
+                      "walk the whole table)"),
             Knob("use_reciprocal", "bool", attacks=("compute",), target=True),
         ),
         suite_shapes=PAGED_SUITE_SHAPES,

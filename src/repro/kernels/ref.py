@@ -107,7 +107,7 @@ def flash_decode_attention(
       k: ``[batch, seq, kv_heads, head_dim]`` key cache.
       v: ``[batch, seq, kv_heads, head_dim]`` value cache.
       kv_len: optional ``[batch]`` int32 valid lengths (entries >= kv_len are
-        masked out). Defaults to the full cache.
+        masked out; a length of 0 gives zeros). Defaults to the full cache.
       sm_scale: softmax scale; defaults to ``1/sqrt(head_dim)``.
 
     Returns:
@@ -129,6 +129,9 @@ def flash_decode_attention(
         mask = jnp.arange(s)[None, :] < kv_len[:, None]  # [b, s]
         scores = jnp.where(mask[:, None, None, :], scores, -jnp.inf)
     probs = jax.nn.softmax(scores, axis=-1)
+    if kv_len is not None:
+        # an all-masked row's softmax is NaN; it attends to nothing
+        probs = jnp.where(mask[:, None, None, :], probs, 0.0)
     out = jnp.einsum("bhgs,bshd->bhgd", probs.astype(v.dtype), v,
                      preferred_element_type=jnp.float32)
     return out.reshape(b, hq, dh).astype(q.dtype)

@@ -46,6 +46,13 @@ class Knob:
     target: Any = None
     note: str = ""
 
+    def value(self, variant) -> Any:
+        """This knob's setting in ``variant``. A pow2 knob left at
+        ``None`` (the kernel sizes it from the input shape) reads as
+        ``lo``, so tile moves have a value to start from."""
+        v = getattr(variant, self.name)
+        return self.lo if v is None and self.kind == "pow2" else v
+
 
 @dataclasses.dataclass(frozen=True)
 class TestCase:

@@ -84,29 +84,33 @@ def init_paged_cache(cfg: ModelConfig, num_pages: int, page_size: int):
 
 
 def decode_step_paged(params, cfg: ModelConfig, pool, page_table, token,
-                      pos, *, seq_shard_axis=None, write_mask=None):
+                      pos, *, seq_shard_axis=None, write_mask=None,
+                      kv_len=None):
     return module_for(cfg).decode_step_paged(
         params, cfg, pool, page_table, token, pos,
-        seq_shard_axis=seq_shard_axis, write_mask=write_mask)
+        seq_shard_axis=seq_shard_axis, write_mask=write_mask, kv_len=kv_len)
 
 
 def decode_cached(params, cfg: ModelConfig, cache, token, pos, *,
-                  page_table=None, seq_shard_axis=None, write_mask=None):
+                  page_table=None, seq_shard_axis=None, write_mask=None,
+                  kv_len=None):
     """One decode step against either cache layout — the single decode
     surface the serving ``CacheManager`` implementations dispatch through:
     ``page_table=None`` selects the contiguous per-slot pool,
     a ``[B, pages_per_slot]`` table selects the paged block pool.
     ``write_mask`` (paged only) routes masked rows' K/V writes to the trap
-    page — the speculative-decoding verify path."""
+    page — the speculative-decoding verify path. ``kv_len`` (paged only,
+    default ``pos + 1``) is the rows each slot's attention reads."""
     if page_table is None:
-        if write_mask is not None:
-            raise ValueError("write_mask requires the paged cache layout "
-                             "(the contiguous pool has no trap page)")
+        if write_mask is not None or kv_len is not None:
+            raise ValueError("write_mask and kv_len require the paged "
+                             "cache layout (the contiguous pool has no "
+                             "trap page)")
         return decode_step(params, cfg, cache, token, pos,
                            seq_shard_axis=seq_shard_axis)
     return decode_step_paged(params, cfg, cache, page_table, token, pos,
                              seq_shard_axis=seq_shard_axis,
-                             write_mask=write_mask)
+                             write_mask=write_mask, kv_len=kv_len)
 
 
 def write_cached(cfg: ModelConfig, cache, new, *, slot=None, pages=None,

@@ -319,7 +319,8 @@ def decode_step(params, cfg: ModelConfig, cache, token, pos, *,
 
 
 def decode_step_paged(params, cfg: ModelConfig, pool, page_table, token,
-                      pos, *, seq_shard_axis=None, write_mask=None):
+                      pos, *, seq_shard_axis=None, write_mask=None,
+                      kv_len=None):
     """One decode step over the paged KV pool.
 
     pool: ``{"k","v": [L, num_pages, page, Hkv, dh]}`` global block pool;
@@ -330,7 +331,10 @@ def decode_step_paged(params, cfg: ModelConfig, pool, page_table, token,
     page instead of their table page — the speculative-decoding verify
     program uses it so rejected draft positions never touch the pool; the
     logits math is untouched (a masked row's output is discarded by the
-    caller).
+    caller). ``kv_len`` (``[B]``, full slot batch, default ``pos + 1``)
+    is the rows each slot's attention reads: the engine passes 0 for a
+    slot that holds no request, so the kernel walks none of its pages
+    (its row then attends to nothing and reads zeros).
 
     The new token's K/V scatter goes through the table —
     ``(page_table[b, pos//page], pos % page)`` — and attention gathers
@@ -369,13 +373,18 @@ def decode_step_paged(params, cfg: ModelConfig, pool, page_table, token,
     hidden = tp.data_shard(hidden)          # this shard's slot rows
     pos_q = tp.data_shard(pos)
     residual = jnp.zeros_like(hidden)
-    kv_len = pos_q + 1
+    kv_len = pos_q + 1 if kv_len is None else tp.data_shard(kv_len)
 
     def body(carry, layer_in):
         p_layer, li = layer_in
         hidden, residual, ks, vs = carry
+        # a layer's pages as [P, page, Hkv * dh] rows, contiguous across
+        # heads: the row write and the kernel's page DMAs share that layout
         k_l = lax.dynamic_index_in_dim(ks, li, 0, keepdims=False)
         v_l = lax.dynamic_index_in_dim(vs, li, 0, keepdims=False)
+        page_shape = k_l.shape
+        k_l = k_l.reshape(*page_shape[:2], -1)
+        v_l = v_l.reshape(*page_shape[:2], -1)
         normed, residual = L.add_rms_norm(hidden, residual,
                                           p_layer["attn_norm"], cfg.norm_eps)
         q, k_new, v_new = L.qkv_proj(p_layer["attn"], normed, cfg)
@@ -383,8 +392,12 @@ def decode_step_paged(params, cfg: ModelConfig, pool, page_table, token,
         k_new = L.rope(k_new, pos_q[:, None], cfg.rope_theta)
         k_w = tp.gather_data(k_new[:, 0])   # full-batch rows for the pool
         v_w = tp.gather_data(v_new[:, 0])
-        k_l = k_l.at[phys, off].set(k_w.astype(k_l.dtype))
-        v_l = v_l.at[phys, off].set(v_w.astype(v_l.dtype))
+        k_l = k_l.at[phys, off].set(
+            k_w.reshape(k_w.shape[0], -1).astype(k_l.dtype)) \
+            .reshape(page_shape)
+        v_l = v_l.at[phys, off].set(
+            v_w.reshape(v_w.shape[0], -1).astype(v_l.dtype)) \
+            .reshape(page_shape)
         ks = lax.dynamic_update_index_in_dim(ks, k_l, li, 0)
         vs = lax.dynamic_update_index_in_dim(vs, v_l, li, 0)
         o = ops.paged_flash_decode_attention(q[:, 0], k_l, v_l, page_table,

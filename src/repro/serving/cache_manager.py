@@ -79,14 +79,15 @@ class CacheManager:
         raise NotImplementedError
 
     def decode(self, params, cache, token, pos, page_table=None,
-               write_mask=None):
+               write_mask=None, kv_len=None):
         """One fused decode step over the pool (traced). ``write_mask``
         (paged only) routes masked rows' K/V writes to the trap page —
         the speculative-decoding verify program rejects draft positions
-        through it."""
+        through it. ``kv_len`` (paged only, default ``pos + 1``) bounds
+        each slot's attention walk."""
         return registry.decode_cached(params, self.cfg, cache, token, pos,
                                       page_table=page_table,
-                                      write_mask=write_mask)
+                                      write_mask=write_mask, kv_len=kv_len)
 
     def read(self, cache, pages):
         """Gather whole pages back into prefill layout (swap-out)."""

@@ -422,7 +422,11 @@ class Engine:
 
         def body(params, cache, token, pos, active, emitted, max_new,
                  keys, temp, topk, topp, page_table=None):
-            logits, cache = cm.decode(params, cache, token, pos, page_table)
+            # a slot that holds no request attends to nothing: its pos
+            # keeps counting, and the paged kernel would walk that far
+            kv_len = jnp.where(active, pos + 1, 0) if paged else None
+            logits, cache = cm.decode(params, cache, token, pos, page_table,
+                                      kv_len=kv_len)
             if greedy_only:
                 # all-greedy specialization: no resident request can draw,
                 # so the step is the historical bare argmax — the sampling
@@ -1495,7 +1499,8 @@ class Engine:
         """Decode steps, prefill retrace count, bucket coverage, scheduler
         counters, the mesh plan (``describe()``), the KV pool's bytes on
         each device (``kv_pool_bytes_per_chip``: one shard on a mesh),
-        and (paged) preemption + page-pool utilization/fragmentation."""
+        and (paged) preemption, page-pool utilization/fragmentation and
+        the attention's page walk (``attn_pages_walked_share``)."""
         prefill_compiles = self._compiles_base \
             + self._admit_fn._cache_size()
         if self._prefix_cache:
@@ -1542,4 +1547,15 @@ class Engine:
         if self.paged:
             out["preempt_mode"] = self.preempt_mode
             out.update(self.cm.stats())
+            out["attn_pages_walked_share"] = self._pages_walked_share()
         return out
+
+    def _pages_walked_share(self) -> float:
+        """Pages the last decode step's attention walked, from the host
+        shadows: each active slot's ``dpos`` rows (that step's length),
+        over every slot's whole table — the walk before lengths bound
+        it. Computed here, never per step."""
+        page = self.page_size
+        walked = sum(-(-min(s.dpos, self.max_seq) // page)
+                     for s in self.slots if s.req is not None and s.dactive)
+        return walked / (self.n_slots * (self.max_seq // page))

@@ -8,6 +8,8 @@ oracles per variant was pure overhead. The heaviest interpret-mode cases
 carry ``@pytest.mark.slow`` (excluded from the tier-1 run, see pytest.ini).
 """
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -160,6 +162,44 @@ def test_paged_flash_decode(shape, dtype, variant, case_cache):
                                     ("paged_flash", shape, str(dtype)), build)
     got = fd._paged_run(variant, q, k, v, kv_len, interpret=True)
     allclose(got, want, dtype)
+
+
+WALK_SHAPES = {"qwen2": (14, 2, 64), "qwen3": (8, 2, 128)}  # hq, hkv, dh
+WALK_PAGE, WALK_PAGES = 16, 16                  # a 256-row table
+
+
+@pytest.mark.parametrize("dtype,ppb", [(F32, 1), (F32, 3), (F32, None),
+                                       (F32, WALK_PAGES), (BF16, None)])
+@pytest.mark.parametrize("shape", sorted(WALK_SHAPES))
+def test_paged_walk_lengths(shape, dtype, ppb):
+    """The slot-grid kernel walks each slot's live blocks only: over a
+    shuffled table, lengths 0, 1, a page -1, a page, a block boundary
+    +-1, the full table and past it (clamped) all match the reference,
+    for one page a block, blocks that do not divide the table, the shape
+    rule (capped at the table) and the whole table a block; a length of
+    0 gives zeros."""
+    hq, hkv, dh = WALK_SHAPES[shape]
+    seq = WALK_PAGE * WALK_PAGES
+    rows = WALK_PAGE * fd.block_pages(
+        ppb, WALK_PAGE, WALK_PAGE * hkv * dh * jnp.dtype(dtype).itemsize,
+        WALK_PAGES)
+    lens = sorted({0, 1, WALK_PAGE - 1, WALK_PAGE, rows - 1, rows,
+                   min(rows + 1, seq), seq, seq + 40})
+    ks = jax.random.split(jax.random.PRNGKey(8), 3)
+    b = len(lens)
+    q = jax.random.normal(ks[0], (b, hq, dh), dtype)
+    k = jax.random.normal(ks[1], (b, seq, hkv, dh), dtype)
+    v = jax.random.normal(ks[2], (b, seq, hkv, dh), dtype)
+    kv_len = jnp.asarray(lens, jnp.int32)
+    k_pages, v_pages, table = fd._page_kv(k, v, WALK_PAGE)
+    variant = dataclasses.replace(fd.PAGED_OPTIMIZED, pages_per_block=ppb)
+    got = fd.paged_flash_decode_attention(q, k_pages, v_pages, table,
+                                          kv_len=kv_len, variant=variant,
+                                          interpret=True)
+    want = ref.paged_flash_decode_attention(
+        q, k_pages, v_pages, table, kv_len=jnp.minimum(kv_len, seq))
+    allclose(got, want, dtype)
+    assert not np.asarray(got[0], np.float32).any()
 
 
 def test_paged_ref_gather_is_bitwise_contiguous():

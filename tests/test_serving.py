@@ -129,6 +129,42 @@ def test_paged_matches_contiguous_pool():
     assert eng.stats()["preemptions"] == 0
 
 
+@pytest.mark.parametrize("pallas", [False, True])
+def test_free_slot_attends_nothing(pallas, monkeypatch):
+    """A slot that holds no request decodes with length 0: its ``pos``,
+    which keeps counting, may drift past ``max_seq`` without changing
+    the active slots' tokens — through the jnp reference and through the
+    Pallas kernel (interpret mode). ``attn_pages_walked_share`` counts the
+    pages the active slots' lengths span."""
+    from repro.kernels import ops
+    if pallas:
+        monkeypatch.setattr(ops, "_use_pallas",
+                            lambda impl: (impl != "ref", True))
+    cfg, params = _setup("qwen2-0.5b")
+    lens, n_steps, page, max_seq = [5, 20], 3, 16, 64
+
+    def serve(drift):
+        eng = Engine(params, cfg, slots=3, max_seq=max_seq,
+                     cache_manager=CacheConfig(page_size=page))
+        for r in _requests(cfg, lens, max_new=12):
+            eng.submit(r)
+        eng.step()                              # admits both: slot 2 free
+        if drift:
+            eng._pos = eng._pos.at[2].set(10 * max_seq)
+        for _ in range(n_steps - 1):
+            eng.step()
+        share = eng.stats()["attn_pages_walked_share"]
+        return {r.rid: list(r.out_tokens) for r in eng.run()}, share
+
+    drifted, share = serve(drift=True)
+    ref, _ = _streams(ReferenceEngine, cfg, params, lens, max_new=12,
+                      slots=3, max_seq=max_seq)
+    assert drifted == serve(drift=False)[0] == ref
+    assert all(len(t) == 12 for t in drifted.values())
+    # lengths 5 + 3 and 20 + 3 span 1 and 2 of 3 slots x 4 pages
+    assert share == (1 + 2) / (3 * max_seq // page)
+
+
 def test_oversubscribed_bit_identical_with_preemption():
     """Oversubscribed pool (requests x lengths > capacity): the engine must
     preempt at least once, swap the victims back in, and still produce

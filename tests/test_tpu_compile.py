@@ -10,14 +10,19 @@ one process may load the TPU library at a time, and every test worker
 imports every test file. Keep these tests in this one file, so that one
 worker loads it."""
 
+import re
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
 
 from repro import configs
 from repro.kernels import ops
 from repro.models import registry
+from repro.sharding import tp
 
 CFG = configs.get("qwen2-0.5b")
 SLOTS, MAX_SEQ, PAGE = 8, 2048, 16
@@ -127,6 +132,74 @@ def test_full_width_paged_decode_step_compiles_for_v5e(one_chip, pallas):
                                           pos)
 
     compiled = _compile(step, params, pool, table, i32, i32)
+    mem = compiled.memory_analysis()
+    used = mem.argument_size_in_bytes + mem.output_size_in_bytes \
+        + mem.temp_size_in_bytes - mem.alias_size_in_bytes
+    assert used < 16 * 2**30
+
+
+# (arch, slots, max_seq, chips): the benchmark's two cells — qwen2-0.5b on
+# one chip, qwen3-8b tensor parallel over a (1, 4) mesh (2 KV heads of 128
+# a chip)
+CELLS = {"qwen2-0.5b.chat": ("qwen2-0.5b", 32, 4096, 1),
+         "qwen3-8b-tp4.batch": ("qwen3-8b", 16, 2048, 4)}
+
+
+@pytest.mark.parametrize("cell", sorted(CELLS))
+def test_cell_decode_step_has_one_paged_kernel(cell, topo, pallas):
+    """The served decode step at each benchmark cell's shape compiles for
+    v5e with exactly one paged attention kernel in its layer loop, found
+    the way the benchmark's trace reader finds it (a ``tpu_custom_call``
+    whose first operand is the flattened ``s32[slots * pages_per_seq]``
+    table), and fits a chip's 16 GiB."""
+    arch, slots, max_seq, chips = CELLS[cell]
+    cfg = configs.get(arch)
+    mesh = Mesh(np.array(topo.devices[:chips]).reshape(1, chips),
+                ("data", "model"))
+    plan = tp.make_plan(cfg, mesh, slots) if chips > 1 else None
+    params = jax.eval_shape(lambda k: registry.init(cfg, k)[0],
+                            jax.random.PRNGKey(0))
+    if chips > 1:                   # the four-chip cell serves bf16 weights
+        params = jax.tree.map(
+            lambda s: jax.ShapeDtypeStruct(s.shape, cfg.jnp_dtype), params)
+    pool = jax.eval_shape(lambda: registry.init_paged_cache(
+        cfg, slots * max_seq // PAGE + 1, PAGE)[0])
+    pspecs = tp.param_specs(params, plan) if plan else \
+        jax.tree.map(lambda _: P(), params)
+    kv = tp.kv_specs(plan) if plan else {"k": P(), "v": P()}
+
+    def place(tree, specs):
+        return jax.tree.map(
+            lambda s, spec: jax.ShapeDtypeStruct(
+                s.shape, s.dtype, sharding=NamedSharding(mesh, spec)),
+            tree, specs, is_leaf=lambda x: isinstance(x, P))
+
+    i32 = jax.ShapeDtypeStruct((slots,), jnp.int32)
+    table = jax.ShapeDtypeStruct((slots, max_seq // PAGE), jnp.int32)
+    rep = (P(),) * 3
+    args = (place(params, pspecs), place(pool, kv)) + tuple(
+        place(x, spec) for x, spec in zip((table, i32, i32), rep))
+
+    def step(params, pool, table, token, pos):
+        return registry.decode_step_paged(params, cfg, pool, table, token,
+                                          pos)
+
+    if plan:
+        fn = tp.wrap(plan, step, (pspecs, kv) + rep, (P(), kv), (1,))
+    else:
+        fn = jax.jit(step, donate_argnums=(1,))
+    compiled = fn.lower(*args).compile()
+    text = compiled.as_text()
+    table_len = slots * max_seq // PAGE
+    kernels = re.findall(r'custom_call_target="tpu_custom_call", '
+                         r'operand_layout_constraints=\{s32\[(\d+)\]',
+                         text)
+    assert kernels == [str(table_len)], kernels
+    (body,) = re.findall(r"\n(%[\w.]+) \([^\n]*\n(?:  [^\n]*\n)*?"
+                         r"[^\n]*operand_layout_constraints=\{s32\["
+                         + str(table_len) + r"\]", text)
+    assert re.search(r"while\([^\n]*body=" + re.escape(body), text), \
+        "the paged kernel is not in the layer loop's body"
     mem = compiled.memory_analysis()
     used = mem.argument_size_in_bytes + mem.output_size_in_bytes \
         + mem.temp_size_in_bytes - mem.alias_size_in_bytes
